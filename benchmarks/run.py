@@ -17,8 +17,9 @@ phase span that was executing when the alarm fired (``repro.obs.phase``
 recorder — e.g. ``sweep:warm`` vs ``sweep:steady``, or fig18's
 table/arbitrate/score breakdown), every module that already finished keeps
 its records, and the JSON is still written — one wedged figure no longer
-loses the whole run.  ``check_regression`` treats marker records as missing
-(note, never a failure).
+loses the whole run.  The run then exits non-zero, naming the modules that
+timed out.  ``check_regression`` treats marker records as missing (note,
+never a failure).
 
 Every run also writes a ``repro.obs`` JSONL manifest (``.obs/``): each
 record mirrors there as it lands, with per-module phase dumps; each JSON
@@ -91,6 +92,9 @@ def main() -> None:
                          "module over budget becomes a timeout marker "
                          "record and the run continues")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.runs < 1:
         ap.error("--runs must be >= 1")
     if args.timeout < 0:
@@ -143,6 +147,7 @@ def main() -> None:
     )
     print("name,us_per_call,derived")
     records = []
+    timed_out = []
     for mod in modules:
         mod_name = mod.__name__.rsplit(".", 1)[-1]
         if args.only and args.only not in mod_name:
@@ -171,6 +176,7 @@ def main() -> None:
             # (check_regression treats it as missing) and move on.  Partial
             # repeats are discarded — a half-measured median is not a median.
             print(f"{mod_name}/TIMEOUT,0,{{}}")
+            timed_out.append(mod_name)
             records.append(
                 {
                     "figure": mod_name,
@@ -219,6 +225,8 @@ def main() -> None:
     if args.json_out:
         write_json(args.json_out, records, full=args.full)
     manifest.close()
+    if timed_out:
+        raise SystemExit(f"modules timed out: {', '.join(timed_out)}")
 
 
 if __name__ == "__main__":

@@ -65,6 +65,7 @@ from .temporal import (  # noqa: F401
 from .sweep import (  # noqa: F401
     SweepRequest,
     SweepResult,
+    lower_sweep,
     sweep,
     sweep_grid,
     sweep_grid_reference,

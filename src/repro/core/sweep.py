@@ -96,17 +96,6 @@ def __getattr__(name: str):
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, check_rep=False):
-    """shard_map across jax versions (jax.shard_map landed in 0.6)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_rep
-    )
-
-
 def chunked_map(item_fn, xs, *, chunk, mesh=None, broadcast=(), tag=None):
     """Map ``item_fn`` over ``xs``'s leading axis in vmapped chunks.
 
@@ -161,10 +150,12 @@ def chunked_map(item_fn, xs, *, chunk, mesh=None, broadcast=(), tag=None):
     else:
         P = jax.sharding.PartitionSpec
         axis = mesh.axis_names[0]
-        out = _shard_map(
+        # check_vma=False: the table merge's loop carry mixes replicated
+        # and per-device values, which the varying-manual-axes check rejects.
+        out = jax.shard_map(
             run, mesh=mesh,
             in_specs=(P(),) * len(broadcast) + (P(axis),),
-            out_specs=P(axis), check_rep=False,
+            out_specs=P(axis), check_vma=False,
         )(*broadcast, chunks)
     return tree.tree_map(
         lambda a: a.reshape((n_chunks * chunk,) + a.shape[2:])[:p], out
@@ -535,18 +526,21 @@ def _afp_from_trial_min_tr(trial_min_tr, tr_values):
     return 1.0 - jnp.mean(ok.astype(jnp.float32), axis=-1)
 
 
-def sweep(request: SweepRequest) -> SweepResult:
-    """Evaluate a ``SweepRequest`` in one jitted call.
+class _Plan(NamedTuple):
+    """Host-side plan of one request: the call of ``_sweep_flat`` and how to
+    shape its output into grids."""
 
-    The single entry point of the engine; ``sweep_policy`` /
-    ``sweep_scheme`` / ``sweep_min_tr`` / ``sweep_grid`` are wrappers over
-    it.  Returns a ``SweepResult`` carrying the grid(s) and the axis
-    metadata (names + coordinate values).
-    """
+    args: tuple          # positional arguments of ``_sweep_flat``
+    kwargs: dict         # its static keywords (plus ``timeline`` when set)
+    shape: tuple         # grid shape of the evaluated points
+    tr_idx: int | None   # axis the TR fast path re-inserts, or None
+    per_point: int       # budgeted working set of one grid point [bytes]
+
+
+def _plan(request: SweepRequest) -> _Plan:
     cfg, units = request.cfg, request.units
     policy, scheme, metric = request.policy, request.scheme, request.metric
     names, points, shape = _grid_points(request.axes)
-    coords = tuple(request.axes[n] for n in names)
 
     if (policy is not None and metric == "eval" and request.tr_fast
             and "tr_mean" in names):
@@ -554,7 +548,6 @@ def sweep(request: SweepRequest) -> SweepResult:
         # then the whole TR axis is a broadcast threshold comparison.
         metric = "trial_min_tr"
         tr_idx = names.index("tr_mean")
-        tr_values = jnp.asarray(request.axes["tr_mean"])
         sub_names = tuple(n for n in names if n != "tr_mean")
         shape = shape[:tr_idx] + shape[tr_idx + 1:]
         if sub_names:
@@ -573,68 +566,92 @@ def sweep(request: SweepRequest) -> SweepResult:
 
         link_chunk = auto_link_chunk(cfg, request.fabric.n_links)
         per_point = scheme_point_bytes(cfg, 2 * link_chunk)
-        chunk = request.chunk_size or int(
-            np.clip(_CHUNK_BUDGET // max(per_point, 1), 1, points.shape[0])
-        )
     else:
         link_chunk = 0
-        chunk = request.chunk_size or _auto_chunk(
-            cfg, units, points.shape[0], scheme
-        )
+        trials = units.u_rlv.shape[0] * units.u_go.shape[0]
+        per_point = (scheme_point_bytes(cfg, trials) if scheme is not None
+                     else policy_point_bytes(cfg, trials))
+    chunk = request.chunk_size or int(
+        np.clip(_CHUNK_BUDGET // max(per_point, 1), 1, points.shape[0])
+    )
     fixed_names = tuple(request.fixed)
     fixed_values = jnp.asarray(
         [float(request.fixed[k]) for k in fixed_names], jnp.float32
     )
-    points_arr = jnp.asarray(points)
-    statics = dict(
+    kwargs = dict(
         policy=policy, scheme=scheme, metric=metric, names=run_names,
         fixed_names=fixed_names, chunk=chunk, backend=request.backend,
         mesh=request.mesh, fabric=request.fabric, link_chunk=link_chunk,
     )
+    if request.timeline is not None:  # else the default: None confuses the
+        kwargs["timeline"] = request.timeline  # AOT pytree signature
+    return _Plan(
+        args=(cfg, units, jnp.asarray(points), fixed_values), kwargs=kwargs,
+        shape=shape, tr_idx=tr_idx, per_point=per_point,
+    )
+
+
+def lower_sweep(request: SweepRequest) -> jax.stages.Lowered:
+    """Lower the one program ``sweep(request)`` runs, without running it.
+
+    For compile audits: ``.compile().memory_analysis()`` gives the device
+    bytes of the program, ``.compile().as_text()`` shows which stages are
+    Pallas kernels (``tpu_custom_call``).
+    """
+    plan = _plan(request)
+    return _sweep_flat.lower(*plan.args, **plan.kwargs)
+
+
+def sweep(request: SweepRequest) -> SweepResult:
+    """Evaluate a ``SweepRequest`` in one jitted call.
+
+    The single entry point of the engine; ``sweep_policy`` /
+    ``sweep_scheme`` / ``sweep_min_tr`` / ``sweep_grid`` are wrappers over
+    it.  Returns a ``SweepResult`` carrying the grid(s) and the axis
+    metadata (names + coordinate values).
+    """
+    plan = _plan(request)
     from repro.obs.phase import current_recorder, measured_call
 
     rec = current_recorder()
     if rec is None:
-        out = _sweep_flat(
-            cfg, units, points_arr, fixed_values,
-            timeline=request.timeline, **statics,
-        )
+        out = _sweep_flat(*plan.args, **plan.kwargs)
     else:
         # Telemetry path: record the chunk plan, then dispatch through
         # ``measured_call`` — a plain call unless the recorder opted into
         # the AOT compile/execute split (memory watermarks vs the budget).
-        trials = units.u_rlv.shape[0] * units.u_go.shape[0]
-        per_point = (scheme_point_bytes(cfg, 2 * link_chunk)
-                     if request.fabric is not None
-                     else scheme_point_bytes(cfg, trials) if scheme is not None
-                     else policy_point_bytes(cfg, trials))
+        n_points = int(plan.args[2].shape[0])
+        chunk = int(plan.kwargs["chunk"])
         rec.note(
-            "sweep.plan", points=int(points_arr.shape[0]), chunk=int(chunk),
-            n_chunks=-(-int(points_arr.shape[0]) // int(chunk)),
-            link_chunk=int(link_chunk), per_point_bytes=int(per_point),
-            budget=_CHUNK_BUDGET, metric=metric,
-            target=scheme if scheme is not None else policy,
+            "sweep.plan", points=n_points, chunk=chunk,
+            n_chunks=-(-n_points // chunk),
+            link_chunk=int(plan.kwargs["link_chunk"]),
+            per_point_bytes=int(plan.per_point),
+            budget=_CHUNK_BUDGET, metric=plan.kwargs["metric"],
+            target=request.scheme if request.scheme is not None
+            else request.policy,
         )
-        if request.timeline is not None:
-            kw = {**statics, "timeline": request.timeline}
-            dyn_kw = {"timeline": request.timeline}
-        else:  # leave the default: None confuses the AOT pytree signature
-            kw, dyn_kw = statics, {}
+        dyn_kw = {k: v for k, v in plan.kwargs.items() if k == "timeline"}
         out = measured_call(
-            "sweep", _sweep_flat,
-            (cfg, units, points_arr, fixed_values), kw,
-            dynamic_args=(units, points_arr, fixed_values),
-            dynamic_kwargs=dyn_kw,
+            "sweep", _sweep_flat, plan.args, plan.kwargs,
+            dynamic_args=plan.args[1:], dynamic_kwargs=dyn_kw,
             budget=_CHUNK_BUDGET,
         )
-    if tr_idx is not None:
-        afp = _afp_from_trial_min_tr(out.reshape(shape + out.shape[1:]), tr_values)
-        data = jnp.moveaxis(afp, -1, tr_idx)
+    names = tuple(request.axes)
+    if plan.tr_idx is not None:
+        tr_values = jnp.asarray(request.axes["tr_mean"])
+        afp = _afp_from_trial_min_tr(
+            out.reshape(plan.shape + out.shape[1:]), tr_values
+        )
+        data = jnp.moveaxis(afp, -1, plan.tr_idx)
     else:
         data = jax.tree_util.tree_map(
-            lambda a: a.reshape(shape + a.shape[1:]), out
+            lambda a: a.reshape(plan.shape + a.shape[1:]), out
         )
-    return SweepResult(data=data, axis_names=names, coords=coords)
+    return SweepResult(
+        data=data, axis_names=names,
+        coords=tuple(request.axes[n] for n in names),
+    )
 
 
 def sweep_grid(

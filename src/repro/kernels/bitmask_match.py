@@ -100,7 +100,8 @@ def _match_kernel(adj_ref, match_wl_ref, ok_ref):
         )
 
         def walk(_, c):
-            match_wl, match_rg, k, active = c
+            match_wl, match_rg, k, active_i = c
+            active = active_i != 0
             k_safe = jnp.maximum(k, 0)
             r = _select_row(parent, k_safe)
             r_safe = jnp.maximum(r, 0)
@@ -110,10 +111,13 @@ def _match_kernel(adj_ref, match_wl_ref, ok_ref):
             upd_rg = active[None, :] & (riota == k_safe[None, :])
             match_rg = jnp.where(upd_rg, r_safe[None, :], match_rg)
             active = active & (r_safe != i) & (prev >= 0)
-            return match_wl, match_rg, jnp.where(active, prev, k), active
+            return (match_wl, match_rg, jnp.where(active, prev, k),
+                    active.astype(jnp.int32))
 
+        # Loop carries are int32: Mosaic cannot carry i1 vectors.
         match_wl, match_rg, _, _ = jax.lax.fori_loop(
-            0, n, walk, (match_wl, match_rg, free_wl, free_wl >= 0)
+            0, n, walk,
+            (match_wl, match_rg, free_wl, (free_wl >= 0).astype(jnp.int32)),
         )
         return match_wl, match_rg
 
@@ -123,20 +127,27 @@ def _match_kernel(adj_ref, match_wl_ref, ok_ref):
 
 
 def _bottleneck_kernel(w_ref, thr_ref):
+    # Per-lane vectors are kept 2-D (1, TB) so every broadcast is a
+    # lane-aligned row broadcast: Mosaic has no sublane broadcast of 1-D
+    # values.  Loop carries are int32 or f32, never bool.
     n, _, tb = w_ref.shape
     w = w_ref[...]                                    # (ring, wl, trial) f32
     riota = _row_iota(n, tb)
     iota3 = jax.lax.broadcasted_iota(jnp.int32, (n, n, tb), 0)
     inf = jnp.float32(jnp.inf)
 
+    def select_row(mat, idx):
+        """mat (N, TB), idx (1, TB) row index per lane -> (1, TB)."""
+        return jnp.sum(jnp.where(riota == idx, mat, 0), axis=0, keepdims=True)
+
     def ring_row(r):
-        """(TB,) ring index per lane -> (N, TB) that ring's weight row."""
-        return jnp.sum(jnp.where(iota3 == r[None, None, :], w, 0.0), axis=0)
+        """(1, TB) ring index per lane -> (N, TB) that ring's weight row."""
+        return jnp.sum(jnp.where(iota3 == r[None], w, 0.0), axis=0)
 
     def first_min(d):
         """(N, TB) -> per-lane (min value, lowest index attaining it)."""
-        dmin = jnp.min(d, axis=0)
-        idx = jnp.min(jnp.where(d == dmin[None, :], riota, n), axis=0)
+        dmin = jnp.min(d, axis=0, keepdims=True)
+        idx = jnp.min(jnp.where(d == dmin, riota, n), axis=0, keepdims=True)
         return dmin, idx
 
     def per_vertex(i, carry):
@@ -149,13 +160,13 @@ def _bottleneck_kernel(w_ref, thr_ref):
             dist, parent, visited = c
             d = jnp.where(visited == 1, inf, dist)
             dk, k = first_min(d)
-            visited = jnp.where(riota == k[None, :], 1, visited)
-            r = _select_row(match_rg, k)              # matched ring or -1
+            visited = jnp.where(riota == k, 1, visited)
+            r = select_row(match_rg, k)               # matched ring or -1
             r_safe = jnp.maximum(r, 0)
-            cand = jnp.maximum(dk[None, :], ring_row(r_safe))
-            better = (r[None, :] >= 0) & (visited == 0) & (cand < dist)
+            cand = jnp.maximum(dk, ring_row(r_safe))
+            better = (r >= 0) & (visited == 0) & (cand < dist)
             dist = jnp.where(better, cand, dist)
-            parent = jnp.where(better, r_safe[None, :], parent)
+            parent = jnp.where(better, r_safe, parent)
             return dist, parent, visited
 
         dist, parent, _ = jax.lax.fori_loop(
@@ -166,19 +177,22 @@ def _bottleneck_kernel(w_ref, thr_ref):
         thr = jnp.maximum(thr, best)
 
         def walk(_, c):
-            match_wl, match_rg, k, active = c
-            r = _select_row(parent, k)
+            match_wl, match_rg, k, active_i = c
+            active = active_i != 0
+            r = select_row(parent, k)
             r_safe = jnp.maximum(r, 0)
-            prev = _select_row(match_wl, r_safe)
-            upd_wl = active[None, :] & (riota == r_safe[None, :])
-            match_wl = jnp.where(upd_wl, k[None, :], match_wl)
-            upd_rg = active[None, :] & (riota == k[None, :])
-            match_rg = jnp.where(upd_rg, r_safe[None, :], match_rg)
+            prev = select_row(match_wl, r_safe)
+            upd_wl = active & (riota == r_safe)
+            match_wl = jnp.where(upd_wl, k, match_wl)
+            upd_rg = active & (riota == k)
+            match_rg = jnp.where(upd_rg, r_safe, match_rg)
             active = active & (r != i)
-            return match_wl, match_rg, jnp.where(active, jnp.maximum(prev, 0), k), active
+            return (match_wl, match_rg,
+                    jnp.where(active, jnp.maximum(prev, 0), k),
+                    active.astype(jnp.int32))
 
         match_wl, match_rg, _, _ = jax.lax.fori_loop(
-            0, n, walk, (match_wl, match_rg, k0, jnp.ones((tb,), bool))
+            0, n, walk, (match_wl, match_rg, k0, jnp.ones((1, tb), jnp.int32))
         )
         return match_wl, match_rg, thr
 
@@ -187,10 +201,10 @@ def _bottleneck_kernel(w_ref, thr_ref):
         (
             jnp.full((n, tb), -1, jnp.int32),
             jnp.full((n, tb), -1, jnp.int32),
-            jnp.full((tb,), -jnp.inf, jnp.float32),
+            jnp.full((1, tb), -jnp.inf, jnp.float32),
         ),
     )
-    thr_ref[0, :] = thr
+    thr_ref[...] = thr
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -82,12 +82,18 @@ def perfect_matching(adj, *, backend="auto"):
     Multiword (T, N, W) uint32 adjacencies (N > 32) run the portable core
     path on every backend: the Pallas matching kernel is single-word by
     layout (one int32 lane per ring), so wide systems route to
-    ``repro.core.matching.max_matching`` rather than failing.
+    ``repro.core.matching.max_matching`` rather than failing.  The route is
+    noted (``kernels.match_route``) in the installed phase recorder, so a
+    run that asked for a kernel can see that none ran.
     """
     backend = _resolve(backend)
     adj = jnp.asarray(adj)
     if adj.ndim >= 3 and adj.dtype == jnp.uint32:      # multiword: core path
         from repro.core.matching import max_matching
+        from repro.obs.phase import note
+
+        note("kernels.match_route", backend=backend, route="core",
+             n=int(adj.shape[-2]))
 
         mw, _ = max_matching(adj)
         return mw, jnp.all(mw >= 0, axis=-1)

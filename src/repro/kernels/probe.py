@@ -43,14 +43,16 @@ def _research_kernel(wl_ref, taken_ref, floor_ref, first_ref, found_ref):
     eiota = jax.lax.broadcasted_iota(jnp.int32, (c, e, tb), 1)
     liota = jax.lax.broadcasted_iota(jnp.int32, (n_lines, tb), 0)
 
+    # The carry is an int32 0/1 mask: Mosaic cannot carry i1 vectors
+    # through a loop.
     def acc_taken(i, acc):
-        t_i = jnp.sum(jnp.where(liota == i, taken, 0), axis=0)   # (TB,)
-        return acc | ((wl == i) & (t_i[None, None, :] > 0))
+        t_i = jnp.sum(jnp.where(liota == i, taken, 0), axis=0, keepdims=True)
+        return acc | ((wl == i) & (t_i[None] > 0)).astype(jnp.int32)
 
     taken_at = jax.lax.fori_loop(
-        0, n_lines, acc_taken, jnp.zeros((c, e, tb), jnp.bool_)
+        0, n_lines, acc_taken, jnp.zeros((c, e, tb), jnp.int32)
     )
-    vis = (wl >= 0) & ~taken_at & (eiota >= floor[:, None, :])
+    vis = (wl >= 0) & (taken_at == 0) & (eiota >= floor[:, None, :])
     first = jnp.min(jnp.where(vis, eiota, e), axis=1)            # (C, TB)
     found = first < e
     first_ref[...] = jnp.where(found, first, -1)

@@ -8,7 +8,7 @@ kernel-shaped mirror of ``repro.core.search_table.build_search_tables``:
 only the *new* candidates are bitonic-sorted (pow2(N*G) rows, the full
 log^2 network), and the buffer join is a single bitonic *merge* of
 M = pow2(E + pow2(N*G)) rows.  The merge input [buffer (ascending), BIG
-pads, sorted block reversed (descending)] is ascending-then-descending —
+pads, block sorted descending] is ascending-then-descending —
 a valid bitonic sequence — so one log2(M)-stage ladder suffices instead
 of re-running the full log^2 sort over the buffer every group (at N=32,
 J=17 that is ~1.3x fewer compare-exchanges; ~2.7x at N=64, where the row
@@ -44,11 +44,13 @@ BIG = 3.0e38  # python literal: Pallas kernels must not capture array consts
 _VMEM_ROWS = 256  # per-merge sort-tile row bound (key + index pair in VMEM)
 
 
-def _bitonic_sort(key, idx):
-    """Ascending bitonic sort along axis 0 by (key, idx) lexicographically.
+def _bitonic_sort_desc(key, idx):
+    """Descending bitonic sort along axis 0 by (key, idx) lexicographically.
 
     The compound key makes the order total on distinct candidates, so the
-    non-stable network still matches the core builder's stable argsort.
+    non-stable network gives exactly the core builder's stable argsort
+    order, reversed.  (Sorting descending replaces a reversal of the
+    ascending sort: Mosaic has no ``rev``.)
     """
     k_len, tb = key.shape
     size = 2
@@ -60,13 +62,13 @@ def _bitonic_sort(key, idx):
             ir = idx.reshape(blocks, 2, stride, tb)
             a_k, b_k = kr[:, 0], kr[:, 1]
             a_i, b_i = ir[:, 0], ir[:, 1]
-            # Ascending iff bit `size` of the element index is 0; within one
-            # 2*stride block that bit is constant = f(block index).
+            # Descending iff bit `size` of the element index is 0; within
+            # one 2*stride block that bit is constant = f(block index).
             blk = jax.lax.broadcasted_iota(jnp.int32, (blocks, stride, tb), 0)
-            asc = (blk * (2 * stride)) & size == 0
+            asc = (blk * (2 * stride)) & size != 0
             gt = (a_k > b_k) | ((a_k == b_k) & (a_i > b_i))
             lt = (a_k < b_k) | ((a_k == b_k) & (a_i < b_i))
-            swap = jnp.where(asc, gt, lt)
+            swap = (asc & gt) | (~asc & lt)   # no select on i1: Mosaic
             new_a_k = jnp.where(swap, b_k, a_k)
             new_b_k = jnp.where(swap, a_k, b_k)
             new_a_i = jnp.where(swap, b_i, a_i)
@@ -121,11 +123,12 @@ def _table_kernel(*refs, max_alias, m_pad, alias_group, has_vis):
     groups = [j_vals[g : g + alias_group] for g in range(0, n_j, alias_group)]
     idx_big = n * n_j  # > every real flat index; pads sort last among BIG ties
 
-    for i in range(n):  # static unroll over rings; working set stays (M, TB)
-        ring_i = ring_ref[i, :][None, :]
-        fsr_i = fsr_ref[i, :][None, :]
-        tr_i = tr_ref[i, :][None, :]
-        vis_i = (vis_ref[i, :, :] != 0) if has_vis else None
+    def per_ring(i, carry):  # one ring at a time; working set stays (M, TB)
+        row = pl.ds(i, 1)
+        ring_i = ring_ref[row, :]
+        fsr_i = fsr_ref[row, :]
+        tr_i = tr_ref[row, :]
+        vis_i = (vis_ref[row, :, :][0] != 0) if has_vis else None
         key = jnp.full((e, tb), BIG, jnp.float32)
         idx = jnp.full((e, tb), idx_big, jnp.int32)
         for g, group in enumerate(groups):  # streaming rank-merge over groups
@@ -146,7 +149,7 @@ def _table_kernel(*refs, max_alias, m_pad, alias_group, has_vis):
                 parts_k.append(jnp.full((gb_pad - gb, tb), BIG, jnp.float32))
                 parts_i.append(jnp.full((gb_pad - gb, tb), idx_big, jnp.int32))
             # Full sort of the new block only; the buffer is already sorted.
-            blk_k, blk_i = _bitonic_sort(
+            blk_k, blk_i = _bitonic_sort_desc(
                 jnp.concatenate(parts_k, axis=0), jnp.concatenate(parts_i, axis=0)
             )
             # [buffer asc, (BIG, idx_big) pads, block desc] ascends to the
@@ -156,19 +159,24 @@ def _table_kernel(*refs, max_alias, m_pad, alias_group, has_vis):
             pad = m_pad - e - gb_pad
             seq_k = [key] + (
                 [jnp.full((pad, tb), BIG, jnp.float32)] if pad else []
-            ) + [jnp.flip(blk_k, axis=0)]
+            ) + [blk_k]
             seq_i = [idx] + (
                 [jnp.full((pad, tb), idx_big, jnp.int32)] if pad else []
-            ) + [jnp.flip(blk_i, axis=0)]
+            ) + [blk_i]
             key, idx = _bitonic_merge(
                 jnp.concatenate(seq_k, axis=0), jnp.concatenate(seq_i, axis=0)
             )
             key, idx = key[:e], idx[:e]
 
         valid = key < BIG
-        delta_ref[i, :, :] = jnp.where(valid, key, float("inf"))
-        wl_ref[i, :, :] = jnp.where(valid, idx // n_j, -1)
-        nv_ref[i, :] = jnp.sum(valid.astype(jnp.int32), axis=0)
+        delta_ref[row, :, :] = jnp.where(valid, key, float("inf"))[None]
+        wl_ref[row, :, :] = jnp.where(valid, idx // n_j, -1)[None]
+        nv_ref[row, :] = jnp.sum(valid.astype(jnp.int32), axis=0, keepdims=True)
+        return carry
+
+    # A loop, not a static unroll: the compiled program holds one ring's
+    # merge network instead of N copies of it.
+    jax.lax.fori_loop(0, n, per_ring, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("max_alias", "max_entries", "interpret"))

@@ -10,16 +10,10 @@ import jax
 
 
 def _make_mesh(shape: tuple, axes: tuple):
-    """``jax.make_mesh`` with explicit Auto axis types where the installed
-    jax exposes them (``jax.sharding.AxisType`` landed after 0.4); older
-    versions default every axis to Auto already, so omitting the kwarg is
-    behaviorally identical."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis explicitly Auto."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -41,8 +35,8 @@ def make_production_mesh(*, multi_pod: bool = False):
 
         return jax.sharding.Mesh(np.asarray(devs[:n]).reshape(shape), axes)
     raise RuntimeError(
-        f"need {n} devices for mesh {shape}, have {len(devs)} — run under "
-        "dryrun.py (sets --xla_force_host_platform_device_count=512)"
+        f"need {n} devices for mesh {shape}, have {len(devs)} — for a CPU "
+        f"rehearsal set XLA_FLAGS=--xla_force_host_platform_device_count={n}"
     )
 
 
@@ -57,11 +51,11 @@ def make_sweep_mesh(n_devices: int | None = None):
     """1-D ``("sweep",)`` mesh for device-parallel ``sweep_grid(mesh=...)``.
 
     Uses the leading ``n_devices`` of whatever exists (all of them by
-    default) — real TPUs, or placeholder CPU devices under dryrun.py's
-    ``--xla_force_host_platform_device_count=512``.  The sweep engine splits
-    its flat chunk axis over this mesh with ``shard_map``; results are
-    bit-identical for every mesh size, so the device count is purely a
-    throughput knob.
+    default) — real TPUs, or virtual CPU devices under
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.  The sweep
+    engine splits its flat chunk axis over this mesh with ``shard_map``;
+    results are bit-identical for every mesh size, so the device count is
+    purely a throughput knob.
     """
     import numpy as np
 
@@ -69,8 +63,8 @@ def make_sweep_mesh(n_devices: int | None = None):
     n = len(devs) if n_devices is None else n_devices
     if n > len(devs):
         raise RuntimeError(
-            f"need {n} devices for a sweep mesh, have {len(devs)} — run under "
-            "dryrun.py (sets --xla_force_host_platform_device_count=512)"
+            f"need {n} devices for a sweep mesh, have {len(devs)} — for a CPU "
+            f"rehearsal set XLA_FLAGS=--xla_force_host_platform_device_count={n}"
         )
     return jax.sharding.Mesh(np.asarray(devs[:n]), ("sweep",))
 

@@ -397,11 +397,7 @@ def moe_ffn_a2a(x, p, cfg: ModelConfig):
         w = gate_l.reshape(-1) * keep.astype(gate_l.dtype)
         return jax.ops.segment_sum(routed * w[:, None], tok, num_segments=t_l)
 
-    try:
-        smap = jax.shard_map  # jax >= 0.6
-    except AttributeError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map as smap
-    y = smap(
+    y = jax.shard_map(
         local_moe,
         mesh=mesh,
         in_specs=(tok_spec, tok_spec, tok_spec, ew_spec, ew_spec, ew_spec),

@@ -14,8 +14,8 @@ function (``fn.lower(*args, **kwargs).compile()``), records the compile span
 and the compiled program's memory watermarks (``memory_analysis()``), then
 executes the compiled artifact with only the *dynamic* arguments (JAX's AOT
 contract: static args are baked into the lowered program and must be omitted
-from the compiled call).  Any failure along the AOT path falls back to a
-plain call, so telemetry can never break a sweep.
+from the compiled call).  A compiler error propagates: telemetry never turns
+a program the backend refuses into a plain re-run.
 """
 from __future__ import annotations
 
@@ -175,24 +175,25 @@ def measured_call(
         with rec.span(label, kind="execute"):
             return fn(*args, **kwargs)
     dynamic_kwargs = dynamic_kwargs or {}
-    try:
-        with rec.span(f"{label}:compile", kind="compile"):
-            compiled = fn.lower(*args, **kwargs).compile()
-        mem = compiled.memory_analysis()
-        if mem is not None:
-            for attr in ("temp_size_in_bytes", "argument_size_in_bytes",
-                         "output_size_in_bytes"):
-                val = getattr(mem, attr, None)
-                if val is not None:
-                    rec.memory(
-                        f"{label}.{attr.split('_size')[0]}",
-                        int(val),
-                        budget=budget if attr == "temp_size_in_bytes" else None,
-                    )
-    except Exception as exc:  # AOT path is best-effort telemetry
-        rec.note(f"{label}.aot_fallback", error=repr(exc))
+    lower = getattr(fn, "lower", None)
+    if lower is None:  # not a jitted callable: nothing to split
+        rec.note(f"{label}.aot_fallback", error="callable has no .lower")
         with rec.span(label, kind="execute"):
             return fn(*args, **kwargs)
+    # Compiler errors propagate: a program the backend refuses is a failure.
+    with rec.span(f"{label}:compile", kind="compile"):
+        compiled = lower(*args, **kwargs).compile()
+    mem = compiled.memory_analysis()
+    if mem is not None:
+        for attr in ("temp_size_in_bytes", "argument_size_in_bytes",
+                     "output_size_in_bytes"):
+            val = getattr(mem, attr, None)
+            if val is not None:
+                rec.memory(
+                    f"{label}.{attr.split('_size')[0]}",
+                    int(val),
+                    budget=budget if attr == "temp_size_in_bytes" else None,
+                )
     with rec.span(f"{label}:execute", kind="execute"):
         out = compiled(*dynamic_args, **dynamic_kwargs)
         import jax

@@ -218,6 +218,24 @@ def test_phase_helpers_are_noops_without_recorder(tables):
     assert np.array_equal(np.asarray(plain), np.asarray(via))
 
 
+def test_measured_call_propagates_compile_errors():
+    """A program the backend refuses is a failure, not a plain re-run: a
+    compiled (non-interpret) Pallas call cannot lower on CPU."""
+    import jax
+    from jax.experimental import pallas as pl
+
+    def copy(x_ref, o_ref):
+        o_ref[...] = x_ref[...]
+
+    fn = jax.jit(lambda x: pl.pallas_call(
+        copy, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype))(x))
+    x = jnp.zeros((8, 128), jnp.float32)
+    rec = PhaseRecorder(measure_memory=True)
+    with use_recorder(rec), pytest.raises(Exception):
+        measured_call("p", fn, (x,), {}, dynamic_args=(x,))
+    assert not any("aot_fallback" in n["name"] for n in rec.notes)
+
+
 def test_recorder_span_nesting_and_current_path():
     rec = PhaseRecorder()
     with use_recorder(rec):

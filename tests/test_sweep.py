@@ -190,7 +190,8 @@ def test_sweep_mesh_size_invariance_subprocess():
     """Mesh size is a pure performance knob: 1-device and 8-placeholder-
     device grids are bit-identical to the unsharded engine (wdm16, so the
     N > 10 bottleneck sweep runs under shard_map too).  Subprocess because
-    jax locks the host device count at first init."""
+    jax locks the host device count at first init; the child is held to the
+    CPU so that it never tries to open an accelerator its parent holds."""
     import os
     import subprocess
     import sys as _sys
@@ -217,6 +218,7 @@ print("MESH_INVARIANT_OK")
     root = Path(__file__).resolve().parent.parent
     env = dict(
         os.environ,
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
         PYTHONPATH=str(root / "src") + os.pathsep + os.environ.get("PYTHONPATH", ""),
     )
