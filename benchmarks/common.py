@@ -36,8 +36,8 @@ def timed_steady(fn: Callable, *args, **kw):
     BENCH_*.json trajectory — compile time is excluded everywhere.  With a
     ``repro.obs.phase`` recorder installed, the warm call is recorded as a
     ``compile`` span and the steady call as ``execute`` — the compile vs
-    execute split the run manifests report — with no recorder it is two
-    no-op context managers around the identical calls.
+    execute split the run manifests report — with no recorder the two
+    spans are only profiler annotations around the identical calls.
     """
     import jax
 

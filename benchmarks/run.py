@@ -117,7 +117,6 @@ def main() -> None:
         fig21_fabric_yield,
         fig22_fabric_chaos,
         kernel_bench,
-        roofline_report,
     )
 
     modules = [
@@ -136,7 +135,6 @@ def main() -> None:
         fig21_fabric_yield,
         fig22_fabric_chaos,
         kernel_bench,
-        roofline_report,
         beyond_lta,
     ]
     from repro.obs.manifest import RunManifest

@@ -41,6 +41,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.phase import stage
+
 from . import ideal, metrics
 from .grid import ArbitrationConfig
 from .matching import adjacency_bitmask
@@ -333,45 +335,48 @@ def _build_tables(cfg, sys: SystemBatch, tr_mean, backend: str | None,
     other rings hold locks.  Every backend threads it to the same
     streaming top-E builder semantics (parity-tested).
     """
-    if backend is None:
-        return build_search_tables(
-            sys, tr_mean, visible=visible, max_alias=cfg.max_fsr_alias
-        )
-    from repro.kernels import ops  # local import: kernels layer is optional
+    with stage("table_build"):
+        if backend is None:
+            return build_search_tables(
+                sys, tr_mean, visible=visible, max_alias=cfg.max_fsr_alias
+            )
+        from repro.kernels import ops  # local import: kernels layer is optional
 
-    delta, wl, nv = ops.build_tables(
-        sys.laser, sys.ring, sys.fsr, tr_mean * sys.tr_unit,
-        visible=visible, max_alias=cfg.max_fsr_alias, backend=backend,
-    )
-    return SearchTables(delta=delta, wl=wl, n_valid=nv)
+        delta, wl, nv = ops.build_tables(
+            sys.laser, sys.ring, sys.fsr, tr_mean * sys.tr_unit,
+            visible=visible, max_alias=cfg.max_fsr_alias, backend=backend,
+        )
+        return SearchTables(delta=delta, wl=wl, n_valid=nv)
 
 
 def _ideal_min_tr(cfg, sys: SystemBatch, policy: str, backend: str | None):
     """(T,) per-trial ideal minimum mean TR, optionally via the kernels."""
-    if backend is None:
-        return ideal.min_tr(sys, policy, jnp.asarray(cfg.s))
-    from repro.kernels import ops
+    with stage("ideal"):
+        if backend is None:
+            return ideal.min_tr(sys, policy, jnp.asarray(cfg.s))
+        from repro.kernels import ops
 
-    if policy == "lta":
-        return ops.bottleneck_threshold(scaled_residual(sys), backend=backend)
-    ltd, ltc = ops.feasibility(
-        sys.laser, sys.ring, sys.fsr, sys.tr_unit,
-        s=tuple(int(v) for v in cfg.s), backend=backend,
-    )
-    return ltd if policy == "ltd" else ltc
+        if policy == "lta":
+            return ops.bottleneck_threshold(scaled_residual(sys), backend=backend)
+        ltd, ltc = ops.feasibility(
+            sys.laser, sys.ring, sys.fsr, sys.tr_unit,
+            s=tuple(int(v) for v in cfg.s), backend=backend,
+        )
+        return ltd if policy == "ltd" else ltc
 
 
 def _ideal_success(cfg, sys: SystemBatch, policy: str, tr_mean, backend: str | None):
     """(T,) bool ideal arbitration success at the given mean tuning range."""
-    if backend is None:
-        return ideal.success(sys, policy, jnp.asarray(cfg.s), tr_mean)
-    if policy == "lta":
-        from repro.kernels import ops
+    with stage("ideal"):
+        if backend is None:
+            return ideal.success(sys, policy, jnp.asarray(cfg.s), tr_mean)
+        if policy == "lta":
+            from repro.kernels import ops
 
-        adj = adjacency_bitmask(reach_matrix(sys, tr_mean))
-        _, ok = ops.perfect_matching(adj, backend=backend)
-        return ok
-    return _ideal_min_tr(cfg, sys, policy, backend) <= tr_mean
+            adj = adjacency_bitmask(reach_matrix(sys, tr_mean))
+            _, ok = ops.perfect_matching(adj, backend=backend)
+            return ok
+        return _ideal_min_tr(cfg, sys, policy, backend) <= tr_mean
 
 
 def _eval_variations(
@@ -416,7 +421,8 @@ def oblivious_arbitrate(
     """
     tables = _build_tables(cfg, sys, tr_mean, backend, visible=visible)
     spec = chain_spec(cfg.s)
-    return scheme_spec(scheme).arbiter(cfg, tables, spec, backend=backend)
+    with stage("arbitrate"):
+        return scheme_spec(scheme).arbiter(cfg, tables, spec, backend=backend)
 
 
 class EvalResult(NamedTuple):
@@ -456,22 +462,24 @@ def evaluate_scheme_impl(
         caller="evaluate_scheme",
     )
     tr = over.resolve("tr_mean", cfg)
-    sys = instantiate(cfg, units, over)
+    with stage("instantiate"):
+        sys = instantiate(cfg, units, over)
     s = jnp.asarray(cfg.s)
     policy = scheme_spec(scheme).policy
     ideal_ok = _ideal_success(cfg, sys, policy, tr, backend)
     assign = oblivious_arbitrate(cfg, sys, tr, scheme, backend=backend)
-    out = classify(assign, s, policy=policy)
-    lock = (out.zero_lock | out.dup_lock) & ideal_ok
-    order = out.order_err & ideal_ok
-    return EvalResult(
-        afp=metrics.afp(ideal_ok),
-        cafp=metrics.cafp(out.success, ideal_ok),
-        lock_err=jnp.mean(lock.astype(jnp.float32)),
-        order_err=jnp.mean(order.astype(jnp.float32)),
-        alg_success=out.success,
-        ideal_ok=ideal_ok,
-    )
+    with stage("classify"):
+        out = classify(assign, s, policy=policy)
+        lock = (out.zero_lock | out.dup_lock) & ideal_ok
+        order = out.order_err & ideal_ok
+        return EvalResult(
+            afp=metrics.afp(ideal_ok),
+            cafp=metrics.cafp(out.success, ideal_ok),
+            lock_err=jnp.mean(lock.astype(jnp.float32)),
+            order_err=jnp.mean(order.astype(jnp.float32)),
+            alg_success=out.success,
+            ideal_ok=ideal_ok,
+        )
 
 
 evaluate_scheme = jax.jit(
@@ -502,9 +510,11 @@ def evaluate_policy_impl(
         caller="evaluate_policy",
     )
     tr = over.resolve("tr_mean", cfg)
-    sys = instantiate(cfg, units, over)
+    with stage("instantiate"):
+        sys = instantiate(cfg, units, over)
     ok = _ideal_success(cfg, sys, policy, tr, backend)
-    return metrics.afp(ok)
+    with stage("classify"):
+        return metrics.afp(ok)
 
 
 evaluate_policy = jax.jit(
@@ -538,7 +548,8 @@ def policy_trial_min_tr_impl(
              sigma_tr_frac=sigma_tr_frac, fsr_mean=fsr_mean),
         caller="policy_min_tr", allow_tr=False,
     )
-    sys = instantiate(cfg, units, over)
+    with stage("instantiate"):
+        sys = instantiate(cfg, units, over)
     return _ideal_min_tr(cfg, sys, policy, backend)
 
 
@@ -562,7 +573,8 @@ def policy_min_tr_impl(
         sigma_fsr_frac=sigma_fsr_frac, sigma_tr_frac=sigma_tr_frac,
         fsr_mean=fsr_mean, backend=backend,
     )
-    return metrics.min_tr_for_complete_success(per_trial)
+    with stage("classify"):
+        return metrics.min_tr_for_complete_success(per_trial)
 
 
 policy_min_tr = jax.jit(

@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.phase import stage
+
 #: Up to this many channels, matching existence and bottleneck thresholds are
 #: evaluated via Hall's condition over all 2^N ring subsets — pure
 #: elementwise/reduction work with no sequential augmenting loops, which is
@@ -269,25 +271,26 @@ def max_matching(adj: jax.Array):
     original path, unchanged) or a multiword (T, N, W) uint32 one from
     ``adjacency_bitmask`` at N > 32, which runs on unpacked bool lanes.
     """
-    if adj.ndim == 3:
-        t, n, _ = adj.shape
-        adj_bool = _unpack_words(adj, n)                   # square: N wls
-        match_wl = jnp.full((t, n), -1, jnp.int32)
-        match_ring = jnp.full((t, n), -1, jnp.int32)
+    with stage("matching"):
+        if adj.ndim == 3:
+            t, n, _ = adj.shape
+            adj_bool = _unpack_words(adj, n)                   # square: N wls
+            match_wl = jnp.full((t, n), -1, jnp.int32)
+            match_ring = jnp.full((t, n), -1, jnp.int32)
 
-        def body_wide(i, carry):
-            return _augment_one_wide(adj_bool, *carry, i=i)
+            def body_wide(i, carry):
+                return _augment_one_wide(adj_bool, *carry, i=i)
 
-        return jax.lax.fori_loop(0, n, body_wide, (match_wl, match_ring))
+            return jax.lax.fori_loop(0, n, body_wide, (match_wl, match_ring))
 
-    T, N = adj.shape
-    match_wl = jnp.full((T, N), -1, jnp.int32)
-    match_ring = jnp.full((T, N), -1, jnp.int32)
+        T, N = adj.shape
+        match_wl = jnp.full((T, N), -1, jnp.int32)
+        match_ring = jnp.full((T, N), -1, jnp.int32)
 
-    def body(i, carry):
-        return _augment_one(adj, *carry, i=i)
+        def body(i, carry):
+            return _augment_one(adj, *carry, i=i)
 
-    return jax.lax.fori_loop(0, N, body, (match_wl, match_ring))
+        return jax.lax.fori_loop(0, N, body, (match_wl, match_ring))
 
 
 def _has_perfect_matching_hall(reach: jax.Array) -> jax.Array:
@@ -313,10 +316,11 @@ def has_perfect_matching(reach: jax.Array) -> jax.Array:
     BFS nests an N-trip ring expansion inside each of N levels); booleans
     are identical to ``max_matching`` (both exact).
     """
-    if reach.shape[-1] <= _HALL_MAX_N:
-        return _has_perfect_matching_hall(reach)
-    weights = jnp.where(reach, jnp.float32(0), jnp.float32(1))
-    return _bottleneck_threshold_sweep(weights) < 0.5
+    with stage("matching"):
+        if reach.shape[-1] <= _HALL_MAX_N:
+            return _has_perfect_matching_hall(reach)
+        weights = jnp.where(reach, jnp.float32(0), jnp.float32(1))
+        return _bottleneck_threshold_sweep(weights) < 0.5
 
 
 def _bottleneck_threshold_sweep(weights: jax.Array) -> jax.Array:
@@ -461,9 +465,10 @@ def bottleneck_matching_threshold(weights: jax.Array) -> jax.Array:
     search (``_bottleneck_threshold_kuhn``, kept as the exactness oracle).
     Returns (T,) float32.
     """
-    if weights.shape[-1] <= _HALL_MAX_N:
-        return _bottleneck_threshold_hall(weights)
-    return _bottleneck_threshold_sweep(weights)
+    with stage("matching"):
+        if weights.shape[-1] <= _HALL_MAX_N:
+            return _bottleneck_threshold_hall(weights)
+        return _bottleneck_threshold_sweep(weights)
 
 
 def _bottleneck_threshold_kuhn(weights: jax.Array, n_steps: int | None = None) -> jax.Array:

@@ -67,6 +67,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.phase import measured_call, note, span, stage
+
 from .api import (
     EvalResult,
     evaluate_policy,
@@ -114,52 +116,52 @@ def chunked_map(item_fn, xs, *, chunk, mesh=None, broadcast=(), tag=None):
     which keeps results bit-identical to the unsharded path and invariant
     to the mesh size.  Composable: with ``mesh=None`` this is vmap-safe,
     so an outer ``chunked_map`` (grid points) may contain an inner one
-    (links per point).
+    (links per point).  The whole map is the ``chunk_loop`` stage; the
+    stages of ``item_fn`` nest inside it.
     """
-    tree = jax.tree_util
-    p = tree.tree_leaves(xs)[0].shape[0]
-    n_chunks = -(-p // chunk)
-    if mesh is not None:
-        n_dev = mesh.devices.size
-        n_chunks = -(-n_chunks // n_dev) * n_dev   # whole chunks per device
-    pad = n_chunks * chunk - p
-    if tag is not None:
-        # Best-effort plan telemetry: this body runs at *trace* time, so
-        # the note fires once per compilation, not per executed chunk.
-        from repro.obs.phase import note
-
-        note(f"chunked_map.{tag}", items=int(p), chunk=int(chunk),
-             n_chunks=int(n_chunks), pad=int(pad))
-    if pad:
-        xs = tree.tree_map(
-            lambda a: jnp.concatenate(
-                [a, jnp.tile(a[-1:], (pad,) + (1,) * (a.ndim - 1))]
-            ),
-            xs,
+    with stage("chunk_loop"):
+        tree = jax.tree_util
+        p = tree.tree_leaves(xs)[0].shape[0]
+        n_chunks = -(-p // chunk)
+        if mesh is not None:
+            n_dev = mesh.devices.size
+            n_chunks = -(-n_chunks // n_dev) * n_dev   # whole chunks per device
+        pad = n_chunks * chunk - p
+        if tag is not None:
+            # Best-effort plan telemetry: this body runs at *trace* time, so
+            # the note fires once per compilation, not per executed chunk.
+            note(f"chunked_map.{tag}", items=int(p), chunk=int(chunk),
+                 n_chunks=int(n_chunks), pad=int(pad))
+        if pad:
+            xs = tree.tree_map(
+                lambda a: jnp.concatenate(
+                    [a, jnp.tile(a[-1:], (pad,) + (1,) * (a.ndim - 1))]
+                ),
+                xs,
+            )
+        chunks = tree.tree_map(
+            lambda a: a.reshape((n_chunks, chunk) + a.shape[1:]), xs
         )
-    chunks = tree.tree_map(
-        lambda a: a.reshape((n_chunks, chunk) + a.shape[1:]), xs
-    )
 
-    def run(*args):
-        *br, ch = args
-        return jax.lax.map(jax.vmap(partial(item_fn, *br)), ch)
+        def run(*args):
+            *br, ch = args
+            return jax.lax.map(jax.vmap(partial(item_fn, *br)), ch)
 
-    if mesh is None:
-        out = run(*broadcast, chunks)
-    else:
-        P = jax.sharding.PartitionSpec
-        axis = mesh.axis_names[0]
-        # check_vma=False: the table merge's loop carry mixes replicated
-        # and per-device values, which the varying-manual-axes check rejects.
-        out = jax.shard_map(
-            run, mesh=mesh,
-            in_specs=(P(),) * len(broadcast) + (P(axis),),
-            out_specs=P(axis), check_vma=False,
-        )(*broadcast, chunks)
-    return tree.tree_map(
-        lambda a: a.reshape((n_chunks * chunk,) + a.shape[2:])[:p], out
-    )
+        if mesh is None:
+            out = run(*broadcast, chunks)
+        else:
+            P = jax.sharding.PartitionSpec
+            axis = mesh.axis_names[0]
+            # check_vma=False: the table merge's loop carry mixes replicated
+            # and per-device values, which the varying-manual-axes check rejects.
+            out = jax.shard_map(
+                run, mesh=mesh,
+                in_specs=(P(),) * len(broadcast) + (P(axis),),
+                out_specs=P(axis), check_vma=False,
+            )(*broadcast, chunks)
+        return tree.tree_map(
+            lambda a: a.reshape((n_chunks * chunk,) + a.shape[2:])[:p], out
+        )
 
 
 def _check_names(names, *, metric: str) -> None:
@@ -259,6 +261,10 @@ class SweepRequest:
     fabric: Any = None
 
     def __post_init__(self):
+        with span("sweep.validate"):
+            self._validate()
+
+    def _validate(self):
         axes = {
             str(k): np.asarray(v, np.float32).reshape(-1)
             for k, v in dict(self.axes).items()
@@ -522,8 +528,9 @@ def _afp_from_trial_min_tr(trial_min_tr, tr_values):
     (ideal success at t == trial_min_tr <= t for every policy) and a mean
     of 0/1 float32 values is order-independent (integer sums < 2^24).
     """
-    ok = trial_min_tr[..., None, :] <= tr_values[:, None]
-    return 1.0 - jnp.mean(ok.astype(jnp.float32), axis=-1)
+    with stage("classify"):
+        ok = trial_min_tr[..., None, :] <= tr_values[:, None]
+        return 1.0 - jnp.mean(ok.astype(jnp.float32), axis=-1)
 
 
 class _Plan(NamedTuple):
@@ -609,45 +616,43 @@ def sweep(request: SweepRequest) -> SweepResult:
     ``sweep_scheme`` / ``sweep_min_tr`` / ``sweep_grid`` are wrappers over
     it.  Returns a ``SweepResult`` carrying the grid(s) and the axis
     metadata (names + coordinate values).
-    """
-    plan = _plan(request)
-    from repro.obs.phase import current_recorder, measured_call
 
-    rec = current_recorder()
-    if rec is None:
-        out = _sweep_flat(*plan.args, **plan.kwargs)
-    else:
-        # Telemetry path: record the chunk plan, then dispatch through
-        # ``measured_call`` — a plain call unless the recorder opted into
-        # the AOT compile/execute split (memory watermarks vs the budget).
-        n_points = int(plan.args[2].shape[0])
-        chunk = int(plan.kwargs["chunk"])
-        rec.note(
-            "sweep.plan", points=n_points, chunk=chunk,
-            n_chunks=-(-n_points // chunk),
-            link_chunk=int(plan.kwargs["link_chunk"]),
-            per_point_bytes=int(plan.per_point),
-            budget=_CHUNK_BUDGET, metric=plan.kwargs["metric"],
-            target=request.scheme if request.scheme is not None
-            else request.policy,
-        )
-        dyn_kw = {k: v for k, v in plan.kwargs.items() if k == "timeline"}
+    Each step is a ``repro.obs.phase`` span, so it shows in a profiler
+    trace (and in an installed recorder): ``sweep.plan`` carries the chunk
+    plan, and each span the executables compiled or loaded inside it
+    (``sweep.dispatch`` ends at the asynchronous return).
+    """
+    with span("sweep.plan") as counters:
+        plan = _plan(request)
+        points, chunk = int(plan.args[2].shape[0]), int(plan.kwargs["chunk"])
+        counters.update(points=points, chunk=chunk, n_chunks=-(-points // chunk),
+                        per_point_bytes=int(plan.per_point),
+                        metric=plan.kwargs["metric"])
+    note("sweep.plan", **counters,
+         link_chunk=int(plan.kwargs["link_chunk"]), budget=_CHUNK_BUDGET,
+         target=request.scheme if request.scheme is not None
+         else request.policy)
+    # ``measured_call`` is a plain call unless an installed recorder opted
+    # into the AOT compile/execute split (memory watermarks vs the budget).
+    dyn_kw = {k: v for k, v in plan.kwargs.items() if k == "timeline"}
+    with span("sweep.dispatch"):
         out = measured_call(
             "sweep", _sweep_flat, plan.args, plan.kwargs,
             dynamic_args=plan.args[1:], dynamic_kwargs=dyn_kw,
             budget=_CHUNK_BUDGET,
         )
     names = tuple(request.axes)
-    if plan.tr_idx is not None:
-        tr_values = jnp.asarray(request.axes["tr_mean"])
-        afp = _afp_from_trial_min_tr(
-            out.reshape(plan.shape + out.shape[1:]), tr_values
-        )
-        data = jnp.moveaxis(afp, -1, plan.tr_idx)
-    else:
-        data = jax.tree_util.tree_map(
-            lambda a: a.reshape(plan.shape + a.shape[1:]), out
-        )
+    with span("sweep.shape"):
+        if plan.tr_idx is not None:
+            tr_values = jnp.asarray(request.axes["tr_mean"])
+            afp = _afp_from_trial_min_tr(
+                out.reshape(plan.shape + out.shape[1:]), tr_values
+            )
+            data = jnp.moveaxis(afp, -1, plan.tr_idx)
+        else:
+            data = jax.tree_util.tree_map(
+                lambda a: a.reshape(plan.shape + a.shape[1:]), out
+            )
     return SweepResult(
         data=data, axis_names=names,
         coords=tuple(request.axes[n] for n in names),

@@ -19,6 +19,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.obs.phase import note, stage
+
 from . import ref
 from .bitmask_match import TRIAL_BLOCK, bottleneck_pallas, match_pallas
 from .feasibility import feasibility_pallas
@@ -90,21 +92,22 @@ def perfect_matching(adj, *, backend="auto"):
     adj = jnp.asarray(adj)
     if adj.ndim >= 3 and adj.dtype == jnp.uint32:      # multiword: core path
         from repro.core.matching import max_matching
-        from repro.obs.phase import note
 
         note("kernels.match_route", backend=backend, route="core",
              n=int(adj.shape[-2]))
 
         mw, _ = max_matching(adj)
         return mw, jnp.all(mw >= 0, axis=-1)
-    adj_c = jnp.swapaxes(adj.astype(jnp.int32), -1, -2)
-    if backend == "jnp":
-        mw, ok = ref.match_ref(adj_c)
-        return jnp.swapaxes(mw, -1, -2), ok
-    t = adj_c.shape[1]
-    tp = _padded_t(t)
-    mw, ok = match_pallas(_pad_cols(adj_c, tp), interpret=(backend == "interpret"))
-    return jnp.swapaxes(mw, -1, -2)[:t], ok[:t]
+    with stage("matching"):
+        adj_c = jnp.swapaxes(adj.astype(jnp.int32), -1, -2)
+        if backend == "jnp":
+            mw, ok = ref.match_ref(adj_c)
+            return jnp.swapaxes(mw, -1, -2), ok
+        t = adj_c.shape[1]
+        tp = _padded_t(t)
+        mw, ok = match_pallas(_pad_cols(adj_c, tp),
+                              interpret=(backend == "interpret"))
+        return jnp.swapaxes(mw, -1, -2)[:t], ok[:t]
 
 
 def bottleneck_threshold(weights, *, backend="auto"):
@@ -115,14 +118,16 @@ def bottleneck_threshold(weights, *, backend="auto"):
     ``moveaxis`` so extra leading vmap axes pass through untouched.
     """
     backend = _resolve(backend)
-    w = jnp.moveaxis(jnp.asarray(weights, jnp.float32), -3, -1)  # (N, N, T)
-    if backend == "jnp":
-        return ref.bottleneck_ref(w)
-    t = w.shape[-1]
-    tp = _padded_t(t)
-    # Padded trials see all-zero weights: threshold 0, sliced off below.
-    thr = bottleneck_pallas(_pad_cols(w, tp), interpret=(backend == "interpret"))
-    return thr[:t]
+    with stage("matching"):
+        w = jnp.moveaxis(jnp.asarray(weights, jnp.float32), -3, -1)  # (N, N, T)
+        if backend == "jnp":
+            return ref.bottleneck_ref(w)
+        t = w.shape[-1]
+        tp = _padded_t(t)
+        # Padded trials see all-zero weights: threshold 0, sliced off below.
+        thr = bottleneck_pallas(_pad_cols(w, tp),
+                                interpret=(backend == "interpret"))
+        return thr[:t]
 
 
 def masked_research(wl, taken, floor, *, backend="auto"):

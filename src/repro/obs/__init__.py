@@ -5,7 +5,10 @@ default with the disabled paths bit-identical to the uninstrumented code:
 
 - ``repro.obs.trace``    per-trial protocol event rings (``run_protocol(trace=)``)
 - ``repro.obs.phase``    timing spans + compiled-memory watermarks (contextvar
-                         recorder picked up by ``sweep``/``bringup``/benchmarks)
+                         recorder picked up by ``sweep``/``bringup``/benchmarks);
+                         its spans always reach a ``jax.profiler`` trace as
+                         host annotations, and ``stage`` names the engine's
+                         stages on the device ops (``arb.<stage>`` op names)
 - ``repro.obs.health``   per-step x per-link chaos health codes
                          (``run_fabric_timeline(health=True)``)
 - ``repro.obs.taxonomy`` post-hoc failure classifier over traces
@@ -20,12 +23,14 @@ from __future__ import annotations
 
 from repro.obs.health import HEALTH_CODES, health_codes, health_matrix_summary
 from repro.obs.phase import (
+    STAGES,
     PhaseRecorder,
     Span,
     current_recorder,
     measured_call,
     note,
     span,
+    stage,
     use_recorder,
 )
 from repro.obs.trace import (
@@ -64,6 +69,7 @@ __all__ = [
     "EVENT_FIELDS",
     "EVENT_KINDS",
     "HEALTH_CODES",
+    "STAGES",
     "PhaseRecorder",
     "Span",
     "TraceBuffer",
@@ -75,6 +81,7 @@ __all__ = [
     "merge_traces",
     "note",
     "span",
+    "stage",
     "trace_append",
     "trace_buffer",
     "trace_events",

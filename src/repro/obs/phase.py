@@ -1,13 +1,31 @@
-"""Phase telemetry: timing spans, compile/execute splits, memory watermarks.
+"""Phase telemetry: timing spans, engine stage names, compile/execute
+splits, memory watermarks.
 
-A ``PhaseRecorder`` collects named ``Span``s (wall-clock segments tagged
-``host``/``compile``/``execute``) and free-form notes (chunk plans, memory
-watermarks vs the 256 MB chunk budget).  It is installed per-scope through a
-contextvar (``use_recorder``); instrumented call sites — ``core.sweep``,
-``fabric.bringup``, ``benchmarks.common.timed_steady`` — look it up with
-``current_recorder()`` and do *nothing* when none is installed, so the
-uninstrumented path stays a plain function call with zero overhead and zero
-behavior change.
+Spans reach the profiler trace.  ``span`` (and ``PhaseRecorder.span``)
+opens a ``jax.profiler.TraceAnnotation`` of the span's name, so a
+``jax.profiler`` trace shows each span on the host timeline, beside the
+device ops it dispatched; counters the span collects (its keyword
+arguments, what the body adds to the dict it yields, and ``compiles``, the
+executables compiled or loaded inside it) become the annotation's
+arguments.  With neither a profiler running nor a recorder installed a
+span costs a flag check.
+
+``stage`` names a stage of the jitted engine with ``jax.named_scope``: the
+name (``arb.<stage>``, one of ``STAGES``) lands in the ``op_name`` metadata
+of every HLO op the stage lowers to, and from there on the device ops of a
+profiler trace.  Scopes change metadata only, never results.  The
+persistent compile cache's key strips that metadata by default, so an entry
+compiled from another tree would be served with that tree's op names;
+importing this module keys the cache on metadata as well.
+
+A ``PhaseRecorder`` collects the same spans as ``Span`` records
+(wall-clock segments tagged ``host``/``compile``/``execute``) plus
+free-form notes (chunk plans, memory watermarks vs the 256 MB chunk
+budget).  It is installed per-scope through a contextvar (``use_recorder``);
+instrumented call sites — ``core.sweep``, ``fabric.bringup``,
+``benchmarks.common.timed_steady`` — look it up with ``current_recorder()``
+and record nothing into it when none is installed; the program's results
+are the same either way.
 
 ``measured_call`` is the compile/execute splitter: it AOT-lowers a jitted
 function (``fn.lower(*args, **kwargs).compile()``), records the compile span
@@ -25,15 +43,91 @@ import time
 from dataclasses import dataclass, field
 from typing import Any
 
+import jax
+
 __all__ = [
+    "STAGES",
+    "STAGE_PREFIX",
     "PhaseRecorder",
     "Span",
+    "compile_count",
     "current_recorder",
     "measured_call",
     "note",
     "span",
+    "stage",
     "use_recorder",
 ]
+
+#: Prefix of every engine stage scope, which sets them apart from the name
+#: stack parts JAX adds itself (``jit(...)``, ``while``, ``vmap(...)``).
+STAGE_PREFIX = "arb."
+
+#: The engine's stages, by where they sit:
+#:   instantiate  ``instantiate`` of a system batch (``core/api.py``)
+#:   ideal        ideal-policy scoring (``_ideal_success``/``_ideal_min_tr``)
+#:   matching     bipartite matching entries (``core/matching.py``, kernel ops)
+#:   table_build  search-table build (``_build_tables``)
+#:   arbitrate    a scheme's arbiter (relation search + SSM, seq, protocol)
+#:   classify     outcome classification and the AFP/CAFP/min-TR reductions
+#:   chunk_loop   ``chunked_map``'s pad, reshape and ``lax.map`` plumbing
+STAGES = ("instantiate", "ideal", "matching", "table_build", "arbitrate",
+          "classify", "chunk_loop")
+
+# Stage names live in op metadata, which the compile cache's key leaves out
+# unless told otherwise.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compiles = 0
+
+
+def _on_duration(event: str, duration: float, **_) -> None:
+    global _compiles
+    if event == _COMPILE_EVENT:
+        _compiles += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def compile_count() -> int:
+    """Executables compiled or loaded from the persistent cache so far in
+    this process (one ``jax.monitoring`` listener counts them)."""
+    return _compiles
+
+
+def stage(name: str):
+    """``jax.named_scope`` of the engine stage ``name`` (one of ``STAGES``)."""
+    if name not in STAGES:
+        raise ValueError(f"unknown stage {name!r}; stages: {STAGES}")
+    return jax.named_scope(STAGE_PREFIX + name)
+
+
+_TRACE_ME = jax.profiler.TraceAnnotation
+
+
+class _Annotated:
+    """A profiler annotation ``name`` around the body; on exit ``args`` and
+    the count of compilations inside (``compiles``) become its arguments.
+    A class, not a generator, for the dispatch path's sake."""
+
+    __slots__ = ("_ann", "_args", "_compiles0")
+
+    def __init__(self, name: str, args: dict):
+        self._ann = _TRACE_ME(name)
+        self._args = args
+
+    def __enter__(self) -> dict:
+        self._compiles0 = _compiles
+        self._ann.__enter__()
+        return self._args
+
+    def __exit__(self, *exc) -> None:
+        self._args["compiles"] = _compiles - self._compiles0
+        if self._ann.is_enabled():
+            self._ann.set_metadata(**self._args)
+        self._ann.__exit__(*exc)
 
 
 @dataclass
@@ -64,10 +158,15 @@ class PhaseRecorder:
     # -- spans ------------------------------------------------------------
     @contextlib.contextmanager
     def span(self, name: str, kind: str = "host", **extra):
+        """Time the body as a ``Span``, under a profiler annotation of the
+        same name; yields the dict of counters (``extra``, whatever the body
+        adds, and ``compiles``), kept as ``Span.extra`` and the annotation's
+        arguments."""
         self._open.append(name)
         t0 = time.perf_counter()
         try:
-            yield
+            with _Annotated(name, extra) as args:
+                yield args
         finally:
             ms = (time.perf_counter() - t0) * 1e3
             self._open.pop()
@@ -131,15 +230,17 @@ def use_recorder(rec: PhaseRecorder):
         _CURRENT.reset(tok)
 
 
-@contextlib.contextmanager
 def span(name: str, kind: str = "host", **extra):
-    """Module-level span: records into the installed recorder, or no-ops."""
+    """Module-level span: a profiler annotation, recorded into the installed
+    recorder as well.  A context manager yielding the span's counter dict
+    (see ``PhaseRecorder.span``).  With neither a recorder nor a running
+    profiler it only yields the dict: it sits on every request's path."""
     rec = _CURRENT.get()
-    if rec is None:
-        yield
-    else:
-        with rec.span(name, kind, **extra):
-            yield
+    if rec is not None:
+        return rec.span(name, kind, **extra)
+    if not _TRACE_ME.is_enabled():   # nobody looks: no annotation to fill
+        return contextlib.nullcontext(extra)
+    return _Annotated(name, extra)
 
 
 def note(name: str, **fields):
@@ -196,6 +297,4 @@ def measured_call(
                 )
     with rec.span(f"{label}:execute", kind="execute"):
         out = compiled(*dynamic_args, **dynamic_kwargs)
-        import jax
-
         return jax.block_until_ready(out)
