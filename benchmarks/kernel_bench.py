@@ -107,7 +107,7 @@ def run(full: bool = False):
               "identical_wl": parity})
         )
 
-    # Rank-merge streaming builder (the core jnp path every sweep runs)
+    # Rank-merge streaming builder (the core jnp path sweeps run off the TPU)
     # across channel counts including the first 64-channel config: timing
     # plus the merge plan actually chosen, with a dense-oracle parity check
     # on a small trial slice at N<=32 (N=64 parity is covered by

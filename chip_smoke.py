@@ -9,7 +9,8 @@ One chip:
   2. WDM16 ``protocol_lta`` at 10,000 trials on a 2-point TR grid;
   3. the 1,008-link WDM16 fabric (``FABRIC_1K``) on a 1-device sweep mesh,
      with fig21's constraints-off parity check on every link;
-  4. ``backend="pallas"`` against the default jnp path, bit for bit, with
+  4. ``backend="pallas"`` against ``backend="jnp"`` (the kernel wrappers'
+     jnp path, whose table build is the core builder), bit for bit, with
      every kernel stage present as a ``tpu_custom_call`` in the program;
   5. engine against ``sweep_reference`` on the chip (bit for bit), and the
      chip against the reference on the host CPU (differences counted).
@@ -197,7 +198,7 @@ def phase_pallas(dev) -> None:
         target = ({"policy": "lta"} if case == "policy_lta"
                   else {"scheme": case})
         req = SweepRequest(cfg=cfg, units=units, axes={"tr_mean": tr},
-                           **target)
+                           backend="jnp", **target)
         ref, ref_cold, ref_steady = _timed(lambda: sweep(req))
         pal_req = req.replace(backend="pallas")
         rec = PhaseRecorder()

@@ -45,8 +45,9 @@ for i, tr in enumerate(results["seq"].axis("tr_mean")):
         f"{float(results['vtrs_ssm'].data.cafp[i]):9.4f}"
     )
 
-# Every stage honors ``backend=``: None (default) is the core jnp path;
-# "jnp" routes table build, ideal scoring and the protocol engine's masked
+# Every stage honors ``backend=``: None (default) is the core jnp path,
+# except that a TPU builds search tables with the Pallas kernel; "jnp"
+# routes table build, ideal scoring and the protocol engine's masked
 # re-search through the kernel wrappers' jnp mirrors, "interpret"/"pallas"
 # select the Pallas kernels (interpreter / real accelerator).  The value
 # reaches every registered scheme arbiter (see the ROADMAP backend

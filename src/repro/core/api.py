@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.obs.phase import stage
+from repro.obs.phase import note, stage
 
 from . import ideal, metrics
 from .grid import ArbitrationConfig
@@ -326,25 +326,47 @@ SCHEMES = _SchemeNamesView()
 SCHEME_POLICY = _SchemePolicyView()
 
 
+def table_route(backend: str | None) -> str:
+    """The search-table builder ``_build_tables`` runs for ``backend``:
+    ``"core"`` (``build_search_tables``, the jnp rank-merge) or a
+    ``repro.kernels.ops`` backend.
+
+    An explicit backend keeps its meaning.  ``None`` is the faster builder
+    on this platform: the Pallas kernel on a TPU, where its bitonic network
+    beats the rank-merge's gathers at every channel count measured (8-32,
+    PERF.md), and the core builder elsewhere.  Both give the same tables
+    bit for bit.
+    """
+    if backend is not None:
+        return backend
+    from repro.kernels import ops  # local import: kernels layer is optional
+
+    return "pallas" if ops.on_tpu() else "core"
+
+
 def _build_tables(cfg, sys: SystemBatch, tr_mean, backend: str | None,
                   visible=None):
-    """Search tables via core jnp (backend=None) or the kernel wrappers.
+    """Search tables from the builder ``table_route`` picks.
 
     ``visible`` ((T, N_wl) or (T, N_ring, N_wl) bool) restricts the search
     to lines still on the bus — the masked re-search a ring runs while
     other rings hold locks.  Every backend threads it to the same
-    streaming top-E builder semantics (parity-tested).
+    streaming top-E builder semantics (parity-tested).  The route is noted
+    (``kernels.table_route``) in the installed phase recorder when traced.
     """
+    route = table_route(backend)
+    note("kernels.table_route", backend=backend, route=route,
+         n=int(sys.laser.shape[-1]))
     with stage("table_build"):
-        if backend is None:
+        if route == "core":
             return build_search_tables(
                 sys, tr_mean, visible=visible, max_alias=cfg.max_fsr_alias
             )
-        from repro.kernels import ops  # local import: kernels layer is optional
+        from repro.kernels import ops
 
         delta, wl, nv = ops.build_tables(
             sys.laser, sys.ring, sys.fsr, tr_mean * sys.tr_unit,
-            visible=visible, max_alias=cfg.max_fsr_alias, backend=backend,
+            visible=visible, max_alias=cfg.max_fsr_alias, backend=route,
         )
         return SearchTables(delta=delta, wl=wl, n_valid=nv)
 
@@ -415,7 +437,8 @@ def oblivious_arbitrate(
     masked re-search tables — the arbitration a late-joining ring performs
     while earlier locks have already captured lines.
 
-    ``backend`` selects the kernel backend for table build *and* is
+    ``backend`` selects the table builder (``table_route``: ``None`` is
+    the Pallas kernel on a TPU and the core builder elsewhere) *and* is
     forwarded to the scheme's arbiter, so backend-aware schemes (the
     protocol engine) run their hot loop on the same backend.
     """

@@ -50,7 +50,9 @@ Engine mechanics (unchanged by the declarative frontend):
     to the unsharded engine and invariant to the mesh size;
   * ``backend`` threads through to the kernel wrappers in
     ``repro.kernels.ops`` (``"jnp"``, ``"interpret"``, ``"pallas"``); the
-    default ``None`` uses the pure-jnp core path.
+    default ``None`` is the engine's best path on the platform: the Pallas
+    table build on a TPU (``table_route``), the pure-jnp core path for
+    everything else.
 
 ``sweep_reference`` keeps the pre-engine per-point loop as the golden
 oracle — the engine is bit-for-bit equal to it (asserted in
@@ -78,6 +80,7 @@ from .api import (
     policy_min_tr,
     policy_min_tr_impl,
     policy_trial_min_tr_impl,
+    table_route,
 )
 from .grid import ArbitrationConfig
 from .matching import _HALL_MAX_N
@@ -206,8 +209,10 @@ class SweepRequest:
             since the streaming top-E table build the per-point scheme
             footprint is ~6x smaller, so scheme sweeps auto-size
             correspondingly larger chunks — fewer ``lax.map`` iterations).
-    backend: kernel backend threaded to ``repro.kernels.ops`` (None = jnp
-            core path).
+    backend: kernel backend threaded to ``repro.kernels.ops``.  None is
+            the engine's best path on the platform: the Pallas table build
+            on a TPU (``repro.core.api.table_route``), the jnp core path
+            for everything else.
     tr_fast: policy-eval sweeps with a ``tr_mean`` axis collapse that axis
             to a free threshold comparison against one per-trial min-TR
             evaluation per remaining point (bit-exact; see
@@ -619,15 +624,18 @@ def sweep(request: SweepRequest) -> SweepResult:
 
     Each step is a ``repro.obs.phase`` span, so it shows in a profiler
     trace (and in an installed recorder): ``sweep.plan`` carries the chunk
-    plan, and each span the executables compiled or loaded inside it
-    (``sweep.dispatch`` ends at the asynchronous return).
+    plan and the table builder (``table_route``; "none" for a policy,
+    which builds no table), and each span the executables compiled or
+    loaded inside it (``sweep.dispatch`` ends at the asynchronous return).
     """
     with span("sweep.plan") as counters:
         plan = _plan(request)
         points, chunk = int(plan.args[2].shape[0]), int(plan.kwargs["chunk"])
         counters.update(points=points, chunk=chunk, n_chunks=-(-points // chunk),
                         per_point_bytes=int(plan.per_point),
-                        metric=plan.kwargs["metric"])
+                        metric=plan.kwargs["metric"],
+                        table_route=("none" if request.scheme is None
+                                     else table_route(request.backend)))
     note("sweep.plan", **counters,
          link_chunk=int(plan.kwargs["link_chunk"]), budget=_CHUNK_BUDGET,
          target=request.scheme if request.scheme is not None

@@ -28,13 +28,18 @@ from .probe import research_pallas
 from .table_build import table_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.devices()[0].platform == "tpu"
+def on_tpu() -> bool:
+    """Whether computations placed by default run on a TPU: the platform of
+    a ``jax.default_device`` scope where one is set, else JAX's default."""
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.default_backend() == "tpu"
+    return (dev if isinstance(dev, str) else dev.platform) == "tpu"
 
 
 def _resolve(backend: str) -> str:
     if backend == "auto":
-        return "pallas" if _on_tpu() else "jnp"
+        return "pallas" if on_tpu() else "jnp"
     if backend not in ("pallas", "interpret", "jnp"):
         raise ValueError(f"unknown backend {backend!r}")
     return backend

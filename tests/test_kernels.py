@@ -144,3 +144,74 @@ def test_table_kernel_visible_masks(vis_ndim):
     np.testing.assert_allclose(np.asarray(d_k)[fin], np.asarray(d_r)[fin], atol=1e-5)
     if vis_ndim == 3:
         assert int(np.asarray(nv_r)[: T // 2].max()) == 0
+
+
+@pytest.mark.parametrize("vis_ndim", [None, 2, 3])
+@pytest.mark.parametrize("n_ch", [8, 16])
+@pytest.mark.parametrize("platform, route", [("tpu", "pallas"), ("cpu", "core")])
+def test_default_table_route_follows_the_platform(platform, route, n_ch,
+                                                  vis_ndim, monkeypatch):
+    """``backend=None`` builds tables with the Pallas kernel on a TPU and
+    with the core rank-merge elsewhere, notes the route it took, and gives
+    ``build_search_tables``'s tables bit for bit.  The Pallas route runs
+    here in interpret mode.
+
+    XLA's CPU backend contracts ``(laser - ring) - j * fsr`` into a fused
+    multiply-add in some compiled programs and not in others, so two
+    builders compiled for the CPU can differ in a delta's last bit.  The
+    inputs are therefore rounded to multiples of 2**-12 nm, on which every
+    term is exact in f32 with or without the contraction: what is left to
+    differ is which candidates a builder keeps and in what order."""
+    import jax
+
+    from repro.core.api import _build_tables
+    from repro.core.search_table import build_search_tables
+    from repro.obs import PhaseRecorder, use_recorder
+
+    called = []
+    build_tables = ops.build_tables
+
+    def interpreted(*args, backend, **kw):
+        called.append(backend)
+        return build_tables(*args, backend="interpret", **kw)
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: platform == "tpu")
+    monkeypatch.setattr(ops, "build_tables", interpreted)
+    cfg, sys = _sys(n_ch=n_ch, seed=7, n=8)
+    exact = lambda a: np.round(np.asarray(a) * 4096) / 4096  # noqa: E731
+    sys = sys._replace(laser=exact(sys.laser), ring=exact(sys.ring),
+                       fsr=exact(sys.fsr))
+    T, N = sys.laser.shape
+    vis = None
+    if vis_ndim is not None:
+        shape = (T, N) if vis_ndim == 2 else (T, N, N)
+        vis = jax.random.bernoulli(jax.random.key(n_ch), 0.5, shape)
+    tr_mean = 0.5 * cfg.grid.fsr
+    rec = PhaseRecorder()
+    with use_recorder(rec):
+        got = _build_tables(cfg, sys, tr_mean, None, visible=vis)
+    notes = [n for n in rec.notes if n["name"] == "kernels.table_route"]
+    assert notes == [{"name": "kernels.table_route", "backend": None,
+                      "route": route, "n": n_ch}]
+    assert called == (["pallas"] if route == "pallas" else [])
+    want = build_search_tables(sys, tr_mean, visible=vis,
+                               max_alias=cfg.max_fsr_alias)
+    for field in ("delta", "wl", "n_valid"):
+        np.testing.assert_array_equal(np.asarray(getattr(got, field)),
+                                      np.asarray(getattr(want, field)))
+
+
+@pytest.mark.parametrize("scope, expected", [(None, True), ("cpu", False),
+                                             ("cpu_device", False)])
+def test_on_tpu_honours_a_default_device_scope(scope, expected, monkeypatch):
+    """On a TPU host, work placed on the CPU by a ``jax.default_device``
+    scope (a platform name or a device) is not TPU work."""
+    import contextlib
+
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    scopes = {None: contextlib.nullcontext(), "cpu": jax.default_device("cpu"),
+              "cpu_device": jax.default_device(jax.devices("cpu")[0])}
+    with scopes[scope]:
+        assert ops.on_tpu() is expected

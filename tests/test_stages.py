@@ -19,7 +19,8 @@ from repro.obs import STAGES, PhaseRecorder, span, stage, use_recorder
 from repro.obs.phase import STAGE_PREFIX, compile_count
 
 SPANS = ("sweep.validate", "sweep.plan", "sweep.dispatch", "sweep.shape")
-PLAN_ARGS = {"points", "chunk", "n_chunks", "per_point_bytes", "metric"}
+PLAN_ARGS = {"points", "chunk", "n_chunks", "per_point_bytes", "metric",
+             "table_route"}
 
 
 def _lta_min_tr(backend=None):
@@ -95,6 +96,7 @@ def test_sweep_spans_reach_the_profiler_trace(tmp_path):
     assert PLAN_ARGS <= set(plan)
     assert (plan["points"], plan["chunk"], plan["n_chunks"]) == (2, 2, 1)
     assert plan["per_point_bytes"] > 0
+    assert plan["table_route"] == "none"        # a policy builds no table
     assert by["sweep.dispatch"]["compiles"] == 0
     assert by["sweep.shape"]["compiles"] == 0
     # one after another on the host: plan, then dispatch, then shape
@@ -118,6 +120,21 @@ def test_recorder_records_the_same_spans_and_plan_note():
     dispatch = next(s for s in rec.spans if s.name == "sweep.dispatch")
     assert dispatch.extra["compiles"] >= 0
     assert np.array_equal(np.asarray(plain.data), np.asarray(sweep(req).data))
+
+
+@pytest.mark.parametrize("backend, route", [(None, "core"), ("jnp", "jnp")])
+def test_plan_names_the_table_builder(backend, route):
+    """A scheme sweep's ``sweep.plan`` carries the builder that its traced
+    program notes (``kernels.table_route``); on the CPU ``None`` is core."""
+    jax.clear_caches()  # the note is made while tracing
+    rec = PhaseRecorder()
+    with use_recorder(rec):
+        sweep(_vtrs_shmoo().replace(backend=backend))
+    plan = next(s for s in rec.spans if s.name == "sweep.plan")
+    assert plan.extra["table_route"] == route
+    routes = {(n["route"], n["n"]) for n in rec.notes
+              if n["name"] == "kernels.table_route"}
+    assert routes == {(route, WDM8_G200.grid.n_ch)}
 
 
 def test_span_counts_the_compiles_inside_it():

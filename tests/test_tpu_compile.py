@@ -78,11 +78,7 @@ def test_kernel_compiles_for_v5e(kernel, n, one_chip):
     assert "tpu_custom_call" in hlo
 
 
-@pytest.mark.parametrize("backend", [None, "pallas"])
-def test_sweep_program_compiles_for_v5e(backend, one_chip):
-    """The WDM8 ``vtrs_ssm`` grid at 24x24 trials, as ``sweep`` would run
-    it, compiled for one chip: the default path has no kernel, the Pallas
-    path has one per stage (table build and LtC feasibility)."""
+def _compiled_sweep(backend, one_chip):
     cfg = WDM8_G200
     units = make_units(cfg, seed=4, n_laser=24, n_ring=24)
     spacing = cfg.grid.grid_spacing
@@ -97,8 +93,36 @@ def test_sweep_program_compiles_for_v5e(backend, one_chip):
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         dyn,
     )
-    compiled = _sweep_flat.lower(cfg_arg, *dyn, **plan.kwargs).compile()
+    return _sweep_flat.lower(cfg_arg, *dyn, **plan.kwargs).compile()
+
+
+@pytest.mark.parametrize("backend", [None, "pallas"])
+def test_sweep_program_compiles_for_v5e(backend, one_chip):
+    """The WDM8 ``vtrs_ssm`` grid at 24x24 trials, as ``sweep`` would run
+    it, compiled for one chip from this CPU process: the default path here
+    is the core one and has no kernel, the Pallas path has one per stage
+    (table build and LtC feasibility)."""
+    compiled = _compiled_sweep(backend, one_chip)
     assert compiled.memory_analysis().temp_size_in_bytes > 0
     hlo = compiled.as_text()
     for kernel in ("table_pallas", "feasibility_pallas"):
         assert (f"jit({kernel})" in hlo) == (backend == "pallas"), kernel
+
+
+def test_default_sweep_on_a_tpu_builds_tables_with_the_kernel(one_chip,
+                                                             monkeypatch):
+    """Where the default platform is a TPU, ``backend=None`` compiles the
+    table build as the Pallas kernel and keeps the core path for ideal
+    scoring (no feasibility kernel)."""
+    from repro.kernels import ops
+
+    # The route is taken while tracing: trace afresh, and leave no program
+    # traced for a TPU behind for the CPU tests that follow.
+    jax.clear_caches()
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    try:
+        hlo = _compiled_sweep(None, one_chip).as_text()
+    finally:
+        jax.clear_caches()
+    assert "jit(table_pallas)" in hlo
+    assert "jit(feasibility_pallas)" not in hlo
