@@ -1,18 +1,29 @@
 """Whether what the timed path returned is correct: a comparison with the
-plain reference (``bench/reference.py``) on a sample of grid points drawn
-from the seed, once the window has closed.
+plain reference on a sample of grid points drawn from the seed, once the
+window has closed.
+
+The reference is the one the traffic file names under ``reference``:
+``bench/references/<name>.py``, loaded with the cell (``bench/spec.py``)
+and carried by the traffic, whose ``answer`` gives one grid point laid out
+like the program's read-back.  A new scheme or policy is a new reference
+module, and nothing here changes.
 
 Each compared number has a limit of its own, in the cell's file
 ``bench/workloads/<cell>.json`` (``PERF.md`` gives the readings it was set
-from):
+from).  Which numbers a point gives follows from the answer's layout:
 
-- scheme requests (``metric="eval"``): ``outcome_mismatch``, the trials
-  whose per-trial outcome (``alg_success``, ``ideal_ok``) differs from the
-  reference's, plus the trials by which each grid field (``afp``,
+- an answer with per-trial outcomes (``alg_success``, ``ideal_ok``):
+  ``outcome_mismatch``, the trials whose per-trial outcome differs from
+  the reference's, plus the trials by which each grid field (``afp``,
   ``cafp``, ``lock_err``, ``order_err``) differs, rounded to whole trials,
   as a share of the per-trial outcomes compared.  float32 rounding of a
   grid field is a small part of one trial and counts nothing;
-- minimum-TR requests: ``min_tr_rel_gap``, the largest relative difference.
+- an answer of single values (such as ``min_tr``): ``<field>_rel_gap``,
+  the largest relative difference of each.
+
+A compared number with no limit in the cell's file, or a per-trial answer
+with a field that the comparison does not know, is refused: no number a
+reference gives goes unchecked.
 
 A sampled point whose answer is missing or malformed reads as infinitely
 far off.  ``compare`` also serves the control, which puts the reference at
@@ -27,21 +38,15 @@ import numpy as np
 from bench import reference
 from bench.traffic import sample_points
 
+_OUTCOMES = ("alg_success", "ideal_ok")
 _FIELDS = ("afp", "cafp", "lock_err", "order_err")
 
 
 def reference_point(traffic, req, flat: int, q=reference.q_f32) -> dict:
     """The reference's answer at one grid point of a request, laid out like
     the program's read-back at that point."""
-    point = req.point(flat)
-    if "scheme" in traffic.target:
-        ans = reference.scheme_point(traffic.cfg, req.units, point,
-                                     traffic.target["scheme"], q)
-        return ans._asdict()
-    if traffic.metric == "min_tr":
-        return {"min_tr": reference.min_tr_point(
-            traffic.cfg, req.units, point, traffic.target["policy"], q)}
-    raise ValueError(f"no reference for {traffic.target} with metric {traffic.metric!r}")
+    return traffic.answer(traffic.cfg, traffic.target, traffic.metric, req.units,
+                          req.point(flat), q)
 
 
 def program_point(answer: dict, shape: tuple, flat: int) -> dict:
@@ -53,17 +58,24 @@ def program_point(answer: dict, shape: tuple, flat: int) -> dict:
 def _gaps(got: dict, want: dict) -> dict:
     """Compared numbers of one point, accumulated as (numerator, denominator)
     for shares and as plain maxima for gaps."""
-    if "min_tr" in want:
-        w = float(want["min_tr"])
-        try:
-            g = float(got["min_tr"])
-        except (KeyError, TypeError, ValueError):
-            g = math.nan
-        gap = abs(g - w) / abs(w) if w else abs(g - w)
-        return {"min_tr_rel_gap": gap if math.isfinite(gap) else math.inf}
+    if "alg_success" not in want:   # single values, each by its relative gap
+        gaps = {}
+        for name, w in want.items():
+            w = float(w)
+            try:
+                g = float(got[name])
+            except (KeyError, TypeError, ValueError):
+                g = math.nan
+            gap = abs(g - w) / abs(w) if w else abs(g - w)
+            gaps[f"{name}_rel_gap"] = gap if math.isfinite(gap) else math.inf
+        return gaps
+    unknown = set(want) - set(_OUTCOMES) - set(_FIELDS)
+    if unknown:
+        raise ValueError(f"per-trial answer with fields the comparison does not know: "
+                         f"{sorted(unknown)}")
     bad, compared = 0, 0
     n_trials = np.asarray(want["alg_success"]).size
-    for name in ("alg_success", "ideal_ok"):
+    for name in _OUTCOMES:
         w = np.asarray(want[name])
         g = np.asarray(got.get(name, ()))
         bad += int(np.sum(g != w)) if g.shape == w.shape else w.size
@@ -107,6 +119,9 @@ def check(traffic, seed: int, requests: list, answers: list, limits: dict,
         got = program_point(answers[r], shape, flat) if answers[r] is not None else {}
         pairs.append((got, want))
     values = compare(pairs)
+    unlimited = sorted(set(values) - set(limits))
+    if unlimited:
+        raise ValueError(f"compared numbers with no limit in the cell file: {unlimited}")
     numbers = {name: {"value": values.get(name, math.inf), "limit": limit}
                for name, limit in limits.items()}
     correct = bool(pairs) and all(

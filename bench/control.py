@@ -13,6 +13,10 @@ points that ``bench/check.py`` compares, then prints one JSON line with
 - ``control``: the same numbers of the reference computed in bfloat16, put
   in the program's place: the upper readings.  The control has to fail.
 
+Both sides take the reference that the traffic file names, loaded with
+the cell, through ``check.reference_point``, so the control is the same
+code one precision down.
+
 Benchmark runs never run this; it needs the chip for the program's side
 and exits non-zero without one.
 """
@@ -31,7 +35,7 @@ def readings(cell, seed: int, n_requests: int, trials: tuple | None = None) -> d
     from bench import check, program, reference
     from bench.traffic import Traffic, sample_points
 
-    traffic = Traffic(cell.traffic, cell.config, trials)
+    traffic = Traffic(cell.traffic, cell.config, trials, cell.reference)
     requests = [traffic.request(seed, i) for i in range(n_requests)]
     pairs = sample_points(seed, requests, int(cell.workload["check"]["sample"]))
     program_cfg = program.build_config(cell.config)

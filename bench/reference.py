@@ -1,4 +1,11 @@
-"""Plain reference of what the timed requests compute, independent of the engine.
+"""Shared plain steps of the benchmark's references, independent of the engine.
+
+Each traffic file names its reference, ``bench/references/<name>.py``
+(found by ``bench/spec.py``), whose ``answer`` computes one grid point of
+what the traffic's target and metric ask for from the steps here:
+``instantiate``, ``scaled_residual``, the ideal policies (``ltc_min_tr``,
+``lta_bottleneck``, ``lta_max_min_tr``), ``search_tables``,
+``relation_search``, ``single_step_matching`` and ``classify``.
 
 Nothing here imports the program under test.  The per-trial arbitration
 logic (search tables, relation search, single-step matching, outcome
@@ -9,9 +16,9 @@ restates the wavelength model of the paper's Eq. 3-4 (the program's
 only the per-trial inputs (wavelengths, residuals, table candidates) are
 computed array-wise with NumPy, in float32 as the configuration states.
 
-Every arithmetic result passes through ``q``: float32 (identity) for the
-reference, bfloat16 rounding for the lower-precision control (``q_bf16``),
-so the control is this same code one precision down.
+Every arithmetic result passes through ``q``: float32 (``q_f32``, the
+identity) for the reference, bfloat16 rounding for the lower-precision
+control (``q_bf16``), so the control is this same code one precision down.
 """
 from __future__ import annotations
 
@@ -276,59 +283,3 @@ def classify(locks: list, s: list, policy: str) -> str:
         ok = True
     return "success" if ok else "order_err"
 
-
-#: Oblivious schemes the reference knows: name -> (VT-RS retry, CAFP policy).
-#: Both are scored against ideal LtC, as the program registers them.
-SCHEMES = {"rs_ssm": (False, "ltc"), "vtrs_ssm": (True, "ltc")}
-
-
-class SchemePoint(NamedTuple):
-    """One grid point of a scheme evaluation, as the engine reports it."""
-
-    afp: float
-    cafp: float
-    lock_err: float
-    order_err: float
-    alg_success: np.ndarray  # (T,) bool
-    ideal_ok: np.ndarray     # (T,) bool
-
-
-def scheme_point(cfg: dict, units: dict, point: dict, scheme: str,
-                 q: Quant = q_f32) -> SchemePoint:
-    """Instantiate, arbitrate with the scheme, and score it against the
-    ideal policy at one grid point (mean TR ``point["tr_mean"]``)."""
-    tolerant, policy = SCHEMES[scheme]
-    s = np.asarray(cfg["target_order"], np.int64)
-    chain = [int(c) for c in np.argsort(s, kind="stable")]
-    sys = instantiate(cfg, units, point, q)
-    tr = F32(point["tr_mean"])
-    res = scaled_residual(sys, q)
-    ideal_ok = ltc_min_tr(res, s) <= tr
-    n = int(cfg["n_ch"])
-    line, n_valid = search_tables(sys, tr, int(cfg["max_fsr_alias"]), 3 * n, q)
-    line, n_valid = line.tolist(), n_valid.tolist()
-    s_list = s.tolist()
-    outcome = []
-    for t in range(len(line)):
-        tables = [line[t][i][:n_valid[t][i]] for i in range(n)]
-        ri = relation_search(tables, chain, tolerant)
-        outcome.append(classify(single_step_matching(tables, chain, ri), s_list, policy))
-    outcome = np.asarray(outcome)
-    success = outcome == "success"
-    lock = np.isin(outcome, ("zero_lock", "dup_lock")) & ideal_ok
-    order = (outcome == "order_err") & ideal_ok
-    return SchemePoint(
-        afp=1.0 - float(ideal_ok.mean()),
-        cafp=float((~success & ideal_ok).mean()),
-        lock_err=float(lock.mean()), order_err=float(order.mean()),
-        alg_success=success, ideal_ok=ideal_ok,
-    )
-
-
-def min_tr_point(cfg: dict, units: dict, point: dict, policy: str,
-                 q: Quant = q_f32) -> float:
-    """The paper's minimum tuning range at one point: the smallest mean TR
-    at which every trial of the batch succeeds under the policy."""
-    if policy != "lta":
-        raise ValueError(f"the reference knows the minimum TR of LtA only, not {policy!r}")
-    return lta_max_min_tr(scaled_residual(instantiate(cfg, units, point, q), q))

@@ -152,7 +152,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices: list,
     from bench import check, program
     from bench.traffic import WARMUP_INDEX, Traffic
 
-    traffic = Traffic(cell.traffic, cell.config, trials)
+    traffic = Traffic(cell.traffic, cell.config, trials, cell.reference)
     program_cfg = program.build_config(cell.config)
     counter = CompileCounter()
 

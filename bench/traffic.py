@@ -4,6 +4,9 @@ A traffic file (``bench/traffic/<name>.json``) is data only:
 
 - ``target``: ``{"scheme": name}`` or ``{"policy": name}``, and ``metric``
   (``"eval"`` or ``"min_tr"``): what every request asks the engine for;
+- ``reference``: the name of the plain reference that computes what
+  ``target`` and ``metric`` ask for, ``bench/references/<name>.py``,
+  loaded by ``bench/spec.load_cell`` (``bench/check.py``);
 - ``axes``: the grid every request carries.  Each axis lists its values
   in a unit of the configuration (``grid_spacing``, ``fsr``, ``nm``), as
   ``{"unit": u, "values": [...]}``, or ``{"linspace": [[a, u], [b, u], n]}``
@@ -90,12 +93,18 @@ class Request(NamedTuple):
 
 
 class Traffic:
-    """Requests of one traffic file under one configuration."""
+    """Requests of one traffic file under one configuration.
 
-    def __init__(self, spec: dict, cfg: dict, trials: tuple | None = None):
+    ``answer`` is the cell's reference (``spec.Cell.reference``), which
+    ``bench/check.py`` asks for the grid points it compares.
+    """
+
+    def __init__(self, spec: dict, cfg: dict, trials: tuple | None = None,
+                 answer=None):
         self.cfg = cfg
         self.target = dict(spec["target"])
         self.metric = spec.get("metric", "eval")
+        self.answer = answer
         self.n_laser, self.n_ring = trials or spec["trials"]
         self.axes = {k: axis_values(cfg, a) for k, a in spec["axes"].items()}
         self.deck = {k: axis_values(cfg, a) for k, a in spec.get("deck", {}).items()}

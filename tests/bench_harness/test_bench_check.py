@@ -22,7 +22,7 @@ def cell(request):
 
 
 def _answers(cell, seed, n):
-    gen = Traffic(cell.traffic, cell.config, TRIALS)
+    gen = Traffic(cell.traffic, cell.config, TRIALS, cell.reference)
     cfg = program.build_config(cell.config)
     reqs = [gen.request(seed, i) for i in range(n)]
     answers = [program.readback(program.submit(
@@ -60,7 +60,7 @@ def test_perturbed_answer_fails_the_comparison(cell):
 
 def test_bfloat16_control_fails_the_comparison(cell):
     """The control: the reference one precision down, in the program's place."""
-    gen = Traffic(cell.traffic, cell.config, TRIALS)
+    gen = Traffic(cell.traffic, cell.config, TRIALS, cell.reference)
     reqs = [gen.request(seed, 0) for seed in (31, 32)]
     pairs = [(check.reference_point(gen, r, p, reference.q_bf16),
               check.reference_point(gen, r, p))

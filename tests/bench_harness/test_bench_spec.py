@@ -88,6 +88,8 @@ def test_cell_resolves_by_name(name):
     assert int(check["sample"]) >= 1 and check["limits"]
     traffic = Traffic(cell.traffic, cell.config)
     assert traffic.n_laser * traffic.n_ring == 10_000   # the paper's 100 x 100
+    assert callable(cell.reference)
+    assert cell.reference.__module__ == f"bench_references_{cell.traffic['reference']}"
 
 
 @pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
@@ -112,7 +114,7 @@ def test_adding_a_cell_needs_only_new_files_and_entries(tmp_path):
     cfg.update(name="wdm8-g400", grid_ghz=400, grid_spacing_nm=2.24,
                ring_bias_nm=8.96, fsr_nm=17.92, tr_mean_nm=17.92)
     (bench_dir / "configs" / "wdm8-g400.json").write_text(json.dumps(cfg))
-    traffic = {"target": {"scheme": "rs_ssm"}, "metric": "eval",
+    traffic = {"target": {"scheme": "rs_ssm"}, "metric": "eval", "reference": "oblivious",
                "axes": {"tr_mean": {"unit": "fsr", "values": [0.4, 0.6]}},
                "deck": {"sigma_rlv": {"unit": "grid_spacing", "values": [1, 2]}},
                "trials": [3, 4]}
@@ -138,6 +140,7 @@ def test_adding_a_cell_needs_only_new_files_and_entries(tmp_path):
                                                     "requests_per_s"]
     read = spec.metric_reader("requests_per_s", bench_dir=bench_dir)
     assert read(type("Run", (), {"requests": [1, 2], "window_s": 4.0})) == 0.5
+    assert callable(cell.reference)
     gen = Traffic(cell.traffic, cell.config)
     req = gen.request(7, 1)
     assert req.n_points == 2 and req.n_trials == 12
