@@ -11,7 +11,8 @@ One chip:
      with fig21's constraints-off parity check on every link;
   4. ``backend="pallas"`` against ``backend="jnp"`` (the kernel wrappers'
      jnp path, whose table build is the core builder), bit for bit, with
-     every kernel stage present as a ``tpu_custom_call`` in the program;
+     every kernel stage present as a ``tpu_custom_call`` in the program,
+     and the kernels the default ``backend=None`` runs on the chip;
   5. engine against ``sweep_reference`` on the chip (bit for bit), and the
      chip against the reference on the host CPU (differences counted).
 
@@ -165,10 +166,18 @@ def phase_fabric(dev) -> None:
           route_cont=res.data.route_cont)
 
 
-#: Kernels each phase-4 request must run on the chip.
+#: Kernels each phase-4 request must run on the chip: with
+#: ``backend="pallas"``, and with the default ``backend=None``
+#: (``table_route``, and ``match_route`` for LtA at 10 < N <= 32 on
+#: batches of 128 trials or more).
 _PALLAS_CASES = {
     "vtrs_ssm": ("table_pallas", "feasibility_pallas"),
     "protocol_lta": ("table_pallas", "match_pallas", "research_pallas"),
+    "policy_lta": ("bottleneck_pallas",),
+}
+_DEFAULT_KERNELS = {
+    "vtrs_ssm": ("table_pallas",),
+    "protocol_lta": ("match_pallas", "table_pallas"),
     "policy_lta": ("bottleneck_pallas",),
 }
 
@@ -210,10 +219,16 @@ def phase_pallas(dev) -> None:
         if missing:
             raise AssertionError(f"{case}: no tpu_custom_call for {missing}")
         routes = [n for n in rec.notes if n["name"].startswith("kernels.")]
+        default = _custom_call_kernels(
+            lower_sweep(req.replace(backend=None)).compile().as_text())
+        if default != sorted(_DEFAULT_KERNELS[case]):
+            raise AssertionError(f"{case}: backend=None ran {default}, "
+                                 f"not {sorted(_DEFAULT_KERNELS[case])}")
         _emit(f"4_pallas_{case}", config="wdm16-g200",
               trials=TRIALS * TRIALS, grid={"tr_mean": tr},
               jnp=_times(ref_cold, ref_steady), pallas=_times(cold, steady),
-              tpu_custom_calls=ran, core_routes=routes, bit_identical=True,
+              tpu_custom_calls=ran, default_custom_calls=default,
+              core_routes=routes, bit_identical=True,
               peak_bytes=_peak_bytes(dev))
 
 

@@ -29,6 +29,13 @@ into the registered name — names stay jit-static cache keys, so every
 variant compiles once and shmoo grids / CAFP scoring come for free.
 ``SCHEMES`` and ``SCHEME_POLICY`` are live views of the registry: schemes
 registered after import are immediately visible.
+
+Every evaluator takes ``backend=`` (None | "jnp" | "pallas" | "interpret").
+``None`` picks each stage's implementation from the platform: the search
+table build from ``table_route`` (the Pallas kernel on a TPU), Lock-to-Any
+matching in ideal scoring from ``match_route`` (the Pallas kernels on a TPU
+at 10 < N <= 32 channels and batches of 128 trials or more), and LtD/LtC
+ideal scoring on the core path.
 """
 from __future__ import annotations
 
@@ -45,7 +52,7 @@ from repro.obs.phase import note, stage
 
 from . import ideal, metrics
 from .grid import ArbitrationConfig
-from .matching import adjacency_bitmask
+from .matching import _HALL_MAX_N, adjacency_bitmask
 from .outcomes import Outcome, classify
 from .reach import reach_matrix, scaled_residual
 from .relation import ChainSpec, chain_spec, relation_search
@@ -326,6 +333,16 @@ SCHEMES = _SchemeNamesView()
 SCHEME_POLICY = _SchemePolicyView()
 
 
+def _kernel_route(backend: str | None, kernel_fits: bool = True) -> str:
+    """``backend`` if set; else the Pallas kernel where the default platform
+    is a TPU and the kernel takes the input, and the core path otherwise."""
+    if backend is not None:
+        return backend
+    from repro.kernels import ops  # local import: kernels layer is optional
+
+    return "pallas" if kernel_fits and ops.on_tpu() else "core"
+
+
 def table_route(backend: str | None) -> str:
     """The search-table builder ``_build_tables`` runs for ``backend``:
     ``"core"`` (``build_search_tables``, the jnp rank-merge) or a
@@ -337,11 +354,39 @@ def table_route(backend: str | None) -> str:
     PERF.md), and the core builder elsewhere.  Both give the same tables
     bit for bit.
     """
-    if backend is not None:
-        return backend
-    from repro.kernels import ops  # local import: kernels layer is optional
+    return _kernel_route(backend)
 
-    return "pallas" if ops.on_tpu() else "core"
+
+def match_route(policy: str, backend: str | None, n: int, trials: int) -> str:
+    """The matching that ideal scoring (``_ideal_min_tr``,
+    ``_ideal_success``) runs for ``policy`` on a batch of ``trials`` systems
+    of ``n`` channels: ``"none"`` where the policy is not Lock-to-Any (no
+    matching runs), else ``"core"`` (``repro.core.ideal``) or a
+    ``repro.kernels.ops`` backend.
+
+    An explicit backend keeps its meaning.  ``None`` is the Pallas kernel on
+    a TPU where the core path is the sequential bottleneck sweep, whose
+    small gathers and scatters the kernel replaces with lane-wise selects,
+    and where the batch's shape suits the kernel; the core path elsewhere:
+
+    * ``n <= _HALL_MAX_N``: the core path is the loop-free Hall formulation;
+    * ``n > 32``: ``ops.perfect_matching`` takes the core path anyway (its
+      kernel holds one bitmask word per ring), and the bottleneck kernel is
+      compiled for v5e only up to 32 channels;
+    * ``trials < TRIAL_BLOCK``: the kernels run trials in 128-lane blocks,
+      so a smaller batch (a fabric link's two endpoints, mapped link by
+      link) would pad every call to a whole block.
+
+    Both bounds on ``n`` are provisional until the chip compares the routes
+    there (PERF.md).  Both routes give the same thresholds and booleans bit
+    for bit.
+    """
+    if policy != "lta":
+        return "none"
+    from repro.kernels.bitmask_match import TRIAL_BLOCK
+
+    return _kernel_route(
+        backend, _HALL_MAX_N < n <= 32 and trials >= TRIAL_BLOCK)
 
 
 def _build_tables(cfg, sys: SystemBatch, tr_mean, backend: str | None,
@@ -371,34 +416,54 @@ def _build_tables(cfg, sys: SystemBatch, tr_mean, backend: str | None,
         return SearchTables(delta=delta, wl=wl, n_valid=nv)
 
 
-def _ideal_min_tr(cfg, sys: SystemBatch, policy: str, backend: str | None):
-    """(T,) per-trial ideal minimum mean TR, optionally via the kernels."""
-    with stage("ideal"):
-        if backend is None:
-            return ideal.min_tr(sys, policy, jnp.asarray(cfg.s))
-        from repro.kernels import ops
+def _lta_route(sys: SystemBatch, backend: str | None) -> str:
+    """LtA ``match_route`` for this (T, N) batch, noted
+    (``kernels.match_route``) in the installed phase recorder when traced."""
+    trials, n = (int(d) for d in sys.laser.shape[-2:])
+    route = match_route("lta", backend, n, trials)
+    note("kernels.match_route", backend=backend, route=route, n=n,
+         trials=trials)
+    return route
 
+
+def _ideal_min_tr(cfg, sys: SystemBatch, policy: str, backend: str | None):
+    """(T,) per-trial ideal minimum mean TR.  LtA runs on the route
+    ``match_route`` picks; LtD/LtC run on the core path unless a kernel
+    backend is given."""
+    with stage("ideal"):
         if policy == "lta":
-            return ops.bottleneck_threshold(scaled_residual(sys), backend=backend)
-        ltd, ltc = ops.feasibility(
-            sys.laser, sys.ring, sys.fsr, sys.tr_unit,
-            s=tuple(int(v) for v in cfg.s), backend=backend,
-        )
-        return ltd if policy == "ltd" else ltc
+            route = _lta_route(sys, backend)
+            if route != "core":
+                from repro.kernels import ops
+
+                return ops.bottleneck_threshold(scaled_residual(sys),
+                                                backend=route)
+        elif backend is not None:
+            from repro.kernels import ops
+
+            ltd, ltc = ops.feasibility(
+                sys.laser, sys.ring, sys.fsr, sys.tr_unit,
+                s=tuple(int(v) for v in cfg.s), backend=backend,
+            )
+            return ltd if policy == "ltd" else ltc
+        return ideal.min_tr(sys, policy, jnp.asarray(cfg.s))
 
 
 def _ideal_success(cfg, sys: SystemBatch, policy: str, tr_mean, backend: str | None):
-    """(T,) bool ideal arbitration success at the given mean tuning range."""
+    """(T,) bool ideal arbitration success at the given mean tuning range,
+    routed as ``_ideal_min_tr`` is."""
     with stage("ideal"):
-        if backend is None:
-            return ideal.success(sys, policy, jnp.asarray(cfg.s), tr_mean)
         if policy == "lta":
-            from repro.kernels import ops
+            route = _lta_route(sys, backend)
+            if route != "core":
+                from repro.kernels import ops
 
-            adj = adjacency_bitmask(reach_matrix(sys, tr_mean))
-            _, ok = ops.perfect_matching(adj, backend=backend)
-            return ok
-        return _ideal_min_tr(cfg, sys, policy, backend) <= tr_mean
+                adj = adjacency_bitmask(reach_matrix(sys, tr_mean))
+                _, ok = ops.perfect_matching(adj, backend=route)
+                return ok
+        elif backend is not None:
+            return _ideal_min_tr(cfg, sys, policy, backend) <= tr_mean
+        return ideal.success(sys, policy, jnp.asarray(cfg.s), tr_mean)
 
 
 def _eval_variations(

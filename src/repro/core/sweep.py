@@ -50,9 +50,10 @@ Engine mechanics (unchanged by the declarative frontend):
     to the unsharded engine and invariant to the mesh size;
   * ``backend`` threads through to the kernel wrappers in
     ``repro.kernels.ops`` (``"jnp"``, ``"interpret"``, ``"pallas"``); the
-    default ``None`` is the engine's best path on the platform: the Pallas
-    table build on a TPU (``table_route``), the pure-jnp core path for
-    everything else.
+    default ``None`` is the engine's best path on the platform: on a TPU the
+    Pallas table build (``table_route``) and the Pallas Lock-to-Any matching
+    at 10 < N <= 32 channels on batches of 128 trials or more
+    (``match_route``), the pure-jnp core path for everything else.
 
 ``sweep_reference`` keeps the pre-engine per-point loop as the golden
 oracle — the engine is bit-for-bit equal to it (asserted in
@@ -77,9 +78,11 @@ from .api import (
     evaluate_policy_impl,
     evaluate_scheme,
     evaluate_scheme_impl,
+    match_route,
     policy_min_tr,
     policy_min_tr_impl,
     policy_trial_min_tr_impl,
+    scheme_spec,
     table_route,
 )
 from .grid import ArbitrationConfig
@@ -210,9 +213,14 @@ class SweepRequest:
             footprint is ~6x smaller, so scheme sweeps auto-size
             correspondingly larger chunks — fewer ``lax.map`` iterations).
     backend: kernel backend threaded to ``repro.kernels.ops``.  None is
-            the engine's best path on the platform: the Pallas table build
-            on a TPU (``repro.core.api.table_route``), the jnp core path
-            for everything else.
+            the engine's best path on the platform: on a TPU the Pallas
+            table build (``repro.core.api.table_route``) and the Pallas
+            Lock-to-Any matching of ideal scoring at 10 < N <= 32 channels
+            on batches of 128 trials or more
+            (``repro.core.api.match_route``), the jnp core path for
+            everything else (LtD/LtC scoring, Hall-size and multiword
+            matching, a fabric link's two-endpoint batches, all of it off
+            a TPU).
     tr_fast: policy-eval sweeps with a ``tr_mean`` axis collapse that axis
             to a free threshold comparison against one per-trial min-TR
             evaluation per remaining point (bit-exact; see
@@ -603,6 +611,21 @@ def _plan(request: SweepRequest) -> _Plan:
     )
 
 
+def _plan_match_route(request: SweepRequest) -> str:
+    """The ``match_route`` of the request's ideal scoring, as its program
+    notes it (``kernels.match_route``): a fabric link scores its two
+    endpoints as one batch, and a temporal sweep of one link does no ideal
+    scoring."""
+    if request.timeline is not None and request.fabric is None:
+        return "none"
+    policy = (request.policy if request.scheme is None
+              else scheme_spec(request.scheme).policy)
+    units = request.units
+    trials = (2 if request.fabric is not None
+              else units.u_rlv.shape[0] * units.u_go.shape[0])
+    return match_route(policy, request.backend, request.cfg.grid.n_ch, trials)
+
+
 def lower_sweep(request: SweepRequest) -> jax.stages.Lowered:
     """Lower the one program ``sweep(request)`` runs, without running it.
 
@@ -624,9 +647,11 @@ def sweep(request: SweepRequest) -> SweepResult:
 
     Each step is a ``repro.obs.phase`` span, so it shows in a profiler
     trace (and in an installed recorder): ``sweep.plan`` carries the chunk
-    plan and the table builder (``table_route``; "none" for a policy,
-    which builds no table), and each span the executables compiled or
-    loaded inside it (``sweep.dispatch`` ends at the asynchronous return).
+    plan, the table builder (``table_route``; "none" for a policy, which
+    builds no table) and the Lock-to-Any matching (``match_route``; "none"
+    where the policy, or the scheme's, is not LtA), and each span the
+    executables compiled or loaded inside it (``sweep.dispatch`` ends at the
+    asynchronous return).
     """
     with span("sweep.plan") as counters:
         plan = _plan(request)
@@ -635,7 +660,8 @@ def sweep(request: SweepRequest) -> SweepResult:
                         per_point_bytes=int(plan.per_point),
                         metric=plan.kwargs["metric"],
                         table_route=("none" if request.scheme is None
-                                     else table_route(request.backend)))
+                                     else table_route(request.backend)),
+                        match_route=_plan_match_route(request))
     note("sweep.plan", **counters,
          link_chunk=int(plan.kwargs["link_chunk"]), budget=_CHUNK_BUDGET,
          target=request.scheme if request.scheme is not None

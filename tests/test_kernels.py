@@ -201,6 +201,74 @@ def test_default_table_route_follows_the_platform(platform, route, n_ch,
                                       np.asarray(getattr(want, field)))
 
 
+@pytest.mark.parametrize("batch", ["block", "pairs"])
+@pytest.mark.parametrize("fn", ["min_tr", "success"])
+@pytest.mark.parametrize("policy, n_ch", [("lta", 8), ("lta", 16), ("lta", 40),
+                                          ("ltc", 16)])
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+def test_default_match_route_follows_the_platform(platform, policy, n_ch, fn,
+                                                  batch, monkeypatch):
+    """``backend=None`` scores ideal LtA with the Pallas kernels on a TPU
+    where the core path is the bottleneck sweep (10 < N <= 32) and the batch
+    fills a 128-trial block, and with the core path at Hall sizes, past 32
+    channels, on batches of two trials mapped one by one (a fabric link's
+    endpoints) and off a TPU; it notes the route it took and gives the core
+    path's thresholds and booleans bit for bit.  LtC notes nothing and runs
+    no kernel.  The Pallas route runs here in interpret mode."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import ideal
+    from repro.core.api import _ideal_min_tr, _ideal_success
+    from repro.obs import PhaseRecorder, use_recorder
+
+    called = []
+
+    def interpreted(name):
+        kernel = getattr(ops, name)
+
+        def run(*args, backend, **kw):
+            called.append((name, backend))
+            return kernel(*args, backend="interpret", **kw)
+
+        return run
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: platform == "tpu")
+    for name in ("bottleneck_threshold", "perfect_matching", "feasibility"):
+        monkeypatch.setattr(ops, name, interpreted(name))
+    cfg = ArbitrationConfig(grid=DWDMGrid(n_ch=n_ch))
+    sys = instantiate(cfg, make_units(cfg, seed=5, n_laser=16, n_ring=8))
+    s = jnp.asarray(cfg.s)
+    tr_mean = float(np.median(np.asarray(ideal.min_tr(sys, policy, s))))
+    if fn == "min_tr":
+        want = ideal.min_tr(sys, policy, s)
+        run = lambda b: _ideal_min_tr(cfg, b, policy, None)
+    else:
+        want = ideal.success(sys, policy, s, tr_mean)
+        run = lambda b: _ideal_success(cfg, b, policy, tr_mean, None)
+        assert 0 < int(np.sum(np.asarray(want))) < sys.n_trials
+    if batch == "pairs":
+        pairs = jax.tree_util.tree_map(
+            lambda a: a.reshape(-1, 2, a.shape[-1]), sys)
+        run = (lambda r: lambda b: jax.vmap(r)(b).reshape(-1))(run)
+        sys = pairs
+    rec = PhaseRecorder()
+    with use_recorder(rec):
+        got = run(sys)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    notes = [n for n in rec.notes if n["name"] == "kernels.match_route"]
+    if policy != "lta":
+        assert notes == [] and called == []
+        return
+    trials = 128 if batch == "block" else 2
+    route = ("pallas" if platform == "tpu" and n_ch == 16 and trials == 128
+             else "core")
+    assert notes == [{"name": "kernels.match_route", "backend": None,
+                      "route": route, "n": n_ch, "trials": trials}]
+    kernel = "bottleneck_threshold" if fn == "min_tr" else "perfect_matching"
+    assert called == ([(kernel, "pallas")] if route == "pallas" else [])
+
+
 @pytest.mark.parametrize("scope, expected", [(None, True), ("cpu", False),
                                              ("cpu_device", False)])
 def test_on_tpu_honours_a_default_device_scope(scope, expected, monkeypatch):

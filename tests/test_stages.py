@@ -20,7 +20,7 @@ from repro.obs.phase import STAGE_PREFIX, compile_count
 
 SPANS = ("sweep.validate", "sweep.plan", "sweep.dispatch", "sweep.shape")
 PLAN_ARGS = {"points", "chunk", "n_chunks", "per_point_bytes", "metric",
-             "table_route"}
+             "table_route", "match_route"}
 
 
 def _lta_min_tr(backend=None):
@@ -97,6 +97,7 @@ def test_sweep_spans_reach_the_profiler_trace(tmp_path):
     assert (plan["points"], plan["chunk"], plan["n_chunks"]) == (2, 2, 1)
     assert plan["per_point_bytes"] > 0
     assert plan["table_route"] == "none"        # a policy builds no table
+    assert plan["match_route"] == "core"        # LtA off a TPU
     assert by["sweep.dispatch"]["compiles"] == 0
     assert by["sweep.shape"]["compiles"] == 0
     # one after another on the host: plan, then dispatch, then shape
@@ -135,6 +136,59 @@ def test_plan_names_the_table_builder(backend, route):
     routes = {(n["route"], n["n"]) for n in rec.notes
               if n["name"] == "kernels.table_route"}
     assert routes == {(route, WDM8_G200.grid.n_ch)}
+    # vtrs_ssm is scored against LtC: no LtA matching runs
+    assert plan.extra["match_route"] == "none"
+    assert not any(n["name"] == "kernels.match_route" for n in rec.notes)
+
+
+@pytest.mark.parametrize("backend, route", [(None, "core"), ("jnp", "jnp")])
+def test_plan_names_the_lta_matching(backend, route):
+    """An LtA sweep's ``sweep.plan`` carries the matching that its traced
+    program notes (``kernels.match_route``); on the CPU ``None`` is core."""
+    jax.clear_caches()  # the note is made while tracing
+    rec = PhaseRecorder()
+    with use_recorder(rec):
+        sweep(_lta_min_tr(backend=backend))
+    plan = next(s for s in rec.spans if s.name == "sweep.plan")
+    assert plan.extra["match_route"] == route
+    routes = {(n["route"], n["n"]) for n in rec.notes
+              if n["name"] == "kernels.match_route"}
+    assert routes == {(route, WDM16_G200.grid.n_ch)}
+
+
+def _plan_case(case):
+    from repro.configs.fabric import FABRIC_TINY
+    from repro.core.temporal import make_timeline
+    from repro.fabric import make_fabric_units
+
+    cfg = WDM16_G200
+    sigma = {"sigma_rlv": np.array([1.0], np.float32)}
+    if case == "fabric":
+        return SweepRequest(cfg=cfg, scheme="protocol_lta", fabric=FABRIC_TINY,
+                            units=make_fabric_units(cfg, FABRIC_TINY, seed=3),
+                            axes=sigma)
+    units = make_units(cfg, seed=2, n_laser=16, n_ring=8 if case != "few" else 4)
+    if case == "timeline":
+        return SweepRequest(cfg=cfg, units=units, scheme="protocol_lta",
+                            axes=sigma, fixed={"tr_mean": 5.0},
+                            timeline=make_timeline(2, cfg.grid.n_ch))
+    return SweepRequest(cfg=cfg, units=units, policy="lta", metric="min_tr",
+                        axes=sigma)
+
+
+@pytest.mark.parametrize("case, route", [("block", "pallas"), ("few", "core"),
+                                         ("fabric", "core"),
+                                         ("timeline", "none")])
+def test_plan_match_route_on_a_tpu(case, route, monkeypatch):
+    """On a TPU the plan names the Pallas matching only where the program's
+    ideal scoring batches a 128-trial block or more at 10 < N <= 32: not
+    for 64 trials, nor for a fabric link's two endpoints; a temporal sweep
+    of one link does no ideal scoring."""
+    from repro.core.sweep import _plan_match_route
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    assert _plan_match_route(_plan_case(case)) == route
 
 
 def test_span_counts_the_compiles_inside_it():

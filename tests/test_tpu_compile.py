@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs.wdm import WDM8_G200
+from repro.configs.wdm import WDM8_G200, WDM16_G200
 from repro.core import SweepRequest, make_units
 from repro.core.sweep import _plan, _sweep_flat
 from repro.kernels.bitmask_match import bottleneck_pallas, match_pallas
@@ -78,6 +78,17 @@ def test_kernel_compiles_for_v5e(kernel, n, one_chip):
     assert "tpu_custom_call" in hlo
 
 
+def _compiled(req, one_chip):
+    """The program ``sweep(req)`` would run, compiled for one chip."""
+    plan = _plan(req)
+    cfg_arg, *dyn = plan.args
+    dyn = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        dyn,
+    )
+    return _sweep_flat.lower(cfg_arg, *dyn, **plan.kwargs).compile()
+
+
 def _compiled_sweep(backend, one_chip):
     cfg = WDM8_G200
     units = make_units(cfg, seed=4, n_laser=24, n_ring=24)
@@ -87,13 +98,7 @@ def _compiled_sweep(backend, one_chip):
         axes={"sigma_rlv": np.array([1.0, 2.0], np.float32) * spacing,
               "tr_mean": np.array([0.4, 0.6], np.float32) * cfg.grid.fsr},
     )
-    plan = _plan(req)
-    cfg_arg, *dyn = plan.args
-    dyn = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        dyn,
-    )
-    return _sweep_flat.lower(cfg_arg, *dyn, **plan.kwargs).compile()
+    return _compiled(req, one_chip)
 
 
 @pytest.mark.parametrize("backend", [None, "pallas"])
@@ -126,3 +131,26 @@ def test_default_sweep_on_a_tpu_builds_tables_with_the_kernel(one_chip,
         jax.clear_caches()
     assert "jit(table_pallas)" in hlo
     assert "jit(feasibility_pallas)" not in hlo
+
+
+def test_default_lta_sweep_on_a_tpu_runs_the_bottleneck_kernel(one_chip,
+                                                               monkeypatch):
+    """Where the default platform is a TPU, ``backend=None`` compiles the
+    WDM16 LtA min-TR sweep (``wdm16-lta-mintr``'s request, at 24x24 trials)
+    with the Pallas bottleneck kernel, and builds no table."""
+    from repro.kernels import ops
+
+    cfg = WDM16_G200
+    req = SweepRequest(
+        cfg=cfg, units=make_units(cfg, seed=4, n_laser=24, n_ring=24),
+        policy="lta", metric="min_tr",
+        axes={"sigma_rlv": np.array([2.0], np.float32) * cfg.grid.grid_spacing},
+    )
+    jax.clear_caches()  # as above: the route is taken while tracing
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    try:
+        hlo = _compiled(req, one_chip).as_text()
+    finally:
+        jax.clear_caches()
+    assert "jit(bottleneck_pallas)" in hlo
+    assert "jit(table_pallas)" not in hlo
